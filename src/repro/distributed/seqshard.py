@@ -22,10 +22,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30  # finite, like attention.py: exp(NEG_INF - m) underflows to 0
@@ -116,5 +113,5 @@ def paged_decode_attention_seqshard(q: jax.Array, k_pages: jax.Array,
     fn = shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(), P()),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     return fn(q, k_pages, v_pages, block_tables, cache_len)

@@ -33,8 +33,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # training axis "model" on purpose: rules that would split a contraction
 # (d_ff, vocab, row-parallel "tp") deliberately do NOT map to it, so a
 # serving mesh only ever moves data with exact collectives (all-gather /
-# masked gather) and a sharded pod's token streams stay bit-identical to
-# the single-device reference even in bf16.
+# masked gather) and, with float32 weights, a sharded pod's token streams
+# stay bit-identical to the single-device reference (bf16: see
+# serve_pspec).
 SERVE_AXIS = "serve"
 
 # Logical dimension name -> preferred mesh axes (in order).
@@ -191,9 +192,11 @@ def serve_pspec(names: Sequence[Optional[str]], shape: Sequence[int],
     row-parallel ``"tp"`` dims of wo / w_down.  With contracting rows
     replicated, every dot runs its full reduction on-device and the only
     cross-device exchanges are exact (all-gathers, masked embedding
-    gathers), so a sharded pod's logits are bitwise those of the
-    single-device reference — the reassociation of a split-K all-reduce
-    in bf16 would flip near-tie argmax tokens.  Non-divisible dims stay
+    gathers), so with float32 weights a sharded pod's logits are bitwise
+    those of the single-device reference — the reassociation of a
+    split-K all-reduce would not be.  With bf16 weights the partitioned
+    program's codegen alone moves logits by about bf16 rounding
+    (distributed/README.md).  Non-divisible dims stay
     replicated (the usual divisibility fallback).
     """
     if len(names) != len(shape):
@@ -237,12 +240,14 @@ def sharding_for(names: Sequence[Optional[str]], shape: Sequence[int],
     return NamedSharding(mesh, resolve_pspec(names, shape, mesh))
 
 
-def tree_shardings(spec_tree: Any, shape_tree: Any, mesh: Mesh) -> Any:
+def tree_shardings(spec_tree: Any, shape_tree: Any, mesh: Mesh,
+                   resolver=resolve_pspec) -> Any:
     """Map a pytree of logical-name tuples + matching ShapeDtypeStructs to
-    NamedShardings (used to build jit in_shardings for the dry-run)."""
+    NamedShardings (jit in/out shardings: the dry-run, or weights made
+    straight into a serving pod's placement with ``serve_pspec``)."""
     return jax.tree_util.tree_map(
         lambda names, sds: NamedSharding(
-            mesh, resolve_pspec(names, sds.shape, mesh)),
+            mesh, resolver(names, sds.shape, mesh)),
         spec_tree, shape_tree,
         is_leaf=lambda x: isinstance(x, tuple) and all(
             isinstance(i, (str, type(None))) for i in x),

@@ -1,12 +1,15 @@
-"""Single-token GQA decode attention Pallas TPU kernel.
+"""Single-token GQA decode attention Pallas TPU kernels (dense and paged).
 
 The serving hot-spot: one query token per sequence attends over a long
-(padded) KV cache.  Grid = (batch, kv-head, kv-blocks); all G query heads of
-a kv group are processed together as a (G x d) tile so the MXU sees a real
-matmul instead of G matvecs — the TPU-native replacement for the GPU
-warp-per-row reductions this kind of kernel uses on CUDA (DESIGN.md).
-Online softmax state lives in VMEM scratch across the sequential kv-block
-dimension; per-row cache lengths arrive via SMEM.
+KV cache.  Grid = (batch, kv-blocks); one grid step streams a
+(block, K, d) tile — every KV head of ``block`` consecutive cache rows —
+and the G query heads of each kv group are processed together as a
+(G x d) tile, so the MXU sees a real matmul instead of G matvecs.  The
+block keeps the whole KV-head axis because Mosaic tiles the last two
+dims of a block: (K, d) is legal as the array's own trailing dims, a
+single head (1, d) is not.  Online softmax state lives in VMEM scratch
+across the sequential kv-block dimension; per-row cache lengths (and the
+paged block tables) arrive as scalar-prefetch operands in SMEM.
 """
 
 from __future__ import annotations
@@ -19,17 +22,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases;
-# accept either so the kernels run on whichever toolchain is baked in.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-            block_s: int, n_blocks: int, window: Optional[int], scale: float):
-    ik = pl.program_id(2)
+def _kernel(*refs, paged: bool, quant: bool, block_s: int, n_blocks: int,
+            n_kv: int, window: Optional[int], scale: float):
+    """One kv-block step for every KV head of one sequence.
+
+    ``refs`` = scalar prefetch (``len_ref`` [, ``tbl_ref``]), then q, k, v
+    [, k_scale, v_scale], the output, and the acc / m / l scratch.  Paged
+    grids walk LOGICAL block slots: the physical page each step streams
+    was picked by the K/V index map from ``tbl_ref``, so only a
+    sequence's own blocks leave HBM; past-the-end slots point at the null
+    block and are masked by ``cache_len`` exactly like dense padding.
+    """
+    n_pre = 2 if paged else 1
+    len_ref = refs[0]
+    q_ref, k_ref, v_ref = refs[n_pre:n_pre + 3]
+    ks_ref, vs_ref = refs[n_pre + 3:n_pre + 5] if quant else (None, None)
+    o_ref, acc_ref, m_ref, l_ref = refs[-4:]
+    ib = pl.program_id(0)
+    ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
@@ -37,7 +52,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    cache_len = len_ref[0]
+    cache_len = len_ref[ib]
     blk_lo = ik * block_s
     live = blk_lo < cache_len
     if window is not None:
@@ -45,154 +60,90 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0, 0, :, :].astype(jnp.float32) * scale  # (G, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
-        pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = pos < cache_len
-        if window is not None:
-            mask &= pos > cache_len - 1 - window
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
+        for h in range(n_kv):
+            q = q_ref[h].astype(jnp.float32) * scale  # (G, d)
+            k = k_ref[:, h, :].astype(jnp.float32)  # (bs, d)
+            v = v_ref[:, h, :].astype(jnp.float32)
+            if quant:  # int8 codes dequantize in VMEM after the HBM load
+                k = k * ks_ref[:, h, :].astype(jnp.float32)  # (bs, 1)
+                v = v * vs_ref[:, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
+            pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = pos < cache_len
+            if window is not None:
+                mask &= pos > cache_len - 1 - window
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[h]  # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())))
+            m_ref[h] = m_new
 
     @pl.when(ik == n_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _kernel_q8(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-               acc_ref, m_ref, l_ref, *, block_s: int, n_blocks: int,
-               scale: float):
-    """int8-KV variant (§Perf D): codes dequantize in VMEM after the HBM
-    load, so the cache streams at 1 byte/element + a scale row."""
-    ik = pl.program_id(2)
+def _decode_call(q: jax.Array, kv: tuple, cache_len: jax.Array,
+                 block_tables: Optional[jax.Array], *, block_s: int,
+                 n_blocks: int, window: Optional[int],
+                 interpret: Optional[bool]) -> jax.Array:
+    """Shared ``pallas_call`` of the four decode variants.
 
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    cache_len = len_ref[0]
-    blk_lo = ik * block_s
-
-    @pl.when(blk_lo < cache_len)
-    def _compute():
-        q = q_ref[0, 0, 0, :, :].astype(jnp.float32) * scale  # (G, d)
-        ks = ks_ref[0, :, 0, :].astype(jnp.float32)  # (bs, 1)
-        vs = vs_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks  # dequant in VMEM
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
-        pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < cache_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
-
-    @pl.when(ik == n_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-
-
-def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, block_s: int, n_blocks: int,
-                  scale: float):
-    """Block-table walk: grid dim 2 is the LOGICAL block index; the
-    physical page each step streams was chosen by the scalar-prefetch
-    index map (``tbl_ref[b, i]``), so only a sequence's own blocks ever
-    leave HBM.  Past-the-end table entries point at the shared null block;
-    its rows are masked by ``cache_len`` exactly like dense padding."""
-    ib = pl.program_id(0)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    cache_len = len_ref[ib]
-    blk_lo = ik * block_s  # logical token offset of this block-table slot
-
-    @pl.when(blk_lo < cache_len)
-    def _compute():
-        q = q_ref[0, 0, 0, :, :].astype(jnp.float32) * scale  # (G, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
-        pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < cache_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
-
-    @pl.when(ik == n_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    ``kv`` is (k, v) or (k, v, k_scale, v_scale) — dense (B, S, K, ...)
+    caches when ``block_tables`` is None, else (N, bs, K, ...) pages.
+    """
+    b, _, h, d = q.shape
+    n_kv = kv[0].shape[2]
+    g = h // n_kv
+    paged = block_tables is not None
+    kernel = functools.partial(
+        _kernel, paged=paged, quant=len(kv) == 4, block_s=block_s,
+        n_blocks=n_blocks, n_kv=n_kv, window=window, scale=d ** -0.5)
+    if paged:
+        def kv_index(ib, ik, len_ref, tbl_ref):
+            return tbl_ref[ib, ik], 0, 0, 0
+        prefetch = (cache_len.astype(jnp.int32),
+                    block_tables.astype(jnp.int32))
+    else:
+        def kv_index(ib, ik, len_ref):
+            return ib, ik, 0, 0
+        prefetch = (cache_len.astype(jnp.int32),)
+    kv_specs = [pl.BlockSpec((None, block_s, n_kv, x.shape[-1]), kv_index)
+                for x in kv]
+    head_spec = pl.BlockSpec((None, n_kv, g, d), lambda ib, ik, *_:
+                             (ib, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, n_blocks),
+        in_specs=[head_spec, *kv_specs],
+        out_specs=head_spec,
+        scratch_shapes=[
+            pltpu.VMEM((n_kv, g, d), jnp.float32),
+            pltpu.VMEM((n_kv, g, 1), jnp.float32),
+            pltpu.VMEM((n_kv, g, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_mode(interpret),
+    )(*prefetch, q.reshape(b, n_kv, g, d), *kv)
+    return out.reshape(b, 1, h, d)
 
 
-def _paged_kernel_q8(len_ref, tbl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                     o_ref, acc_ref, m_ref, l_ref, *, block_s: int,
-                     n_blocks: int, scale: float):
-    """int8-KV paged variant: codes + per-row scales stream per physical
-    block and dequantize in VMEM (1 byte/element over the wire)."""
-    ib = pl.program_id(0)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    cache_len = len_ref[ib]
-    blk_lo = ik * block_s
-
-    @pl.when(blk_lo < cache_len)
-    def _compute():
-        q = q_ref[0, 0, 0, :, :].astype(jnp.float32) * scale  # (G, d)
-        ks = ks_ref[0, :, 0, :].astype(jnp.float32)  # (bs, 1)
-        vs = vs_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks  # dequant in VMEM
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
-        pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < cache_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
-
-    @pl.when(ik == n_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+def _dense_blocks(s: int, block_s: int) -> tuple[int, int]:
+    block_s = min(block_s, s)
+    if s % block_s:
+        raise ValueError("cache length must divide block_s")
+    return block_s, s // block_s
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -203,53 +154,19 @@ def paged_decode_attention_pallas(
     block_tables: jax.Array,  # (B, M) int32
     cache_len: jax.Array,     # (B,) int32
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Paged single-token GQA decode: grid = (batch, kv-head, table slot).
+    """Paged single-token GQA decode: grid = (batch, table slot).
 
     ``block_tables`` and ``cache_len`` ride in as scalar-prefetch operands
     (``pltpu.PrefetchScalarGridSpec``) so the K/V index maps can pick the
     PHYSICAL page for each logical slot before the DMA is issued — the
-    TPU-native equivalent of vLLM's gather-free paged attention.
+    TPU-native equivalent of vLLM's paged attention.
     """
-    b, _, h, d = q.shape
-    _, bs, n_kv, _ = k_pages.shape
-    m = block_tables.shape[1]
-    g = h // n_kv
-
-    kernel = functools.partial(_paged_kernel, block_s=bs, n_blocks=m,
-                               scale=d ** -0.5)
-    qg = q.reshape(b, 1, n_kv, g, d)
-    kv_spec = pl.BlockSpec(
-        (1, bs, 1, d),
-        lambda ib, ih, ik, len_ref, tbl_ref: (tbl_ref[ib, ik], 0, ih, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_kv, m),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, g, d),
-                         lambda ib, ih, ik, *_: (ib, 0, ih, 0, 0)),
-            kv_spec, kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda ib, ih, ik, *_: (ib, ih, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(cache_len.astype(jnp.int32), block_tables.astype(jnp.int32),
-      qg, k_pages, v_pages)
-    return out.reshape(b, 1, h, d)
+    return _decode_call(q, (k_pages, v_pages), cache_len, block_tables,
+                        block_s=k_pages.shape[1],
+                        n_blocks=block_tables.shape[1], window=None,
+                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -262,49 +179,14 @@ def paged_decode_attention_quant_pallas(
     block_tables: jax.Array,  # (B, M) int32
     cache_len: jax.Array,     # (B,) int32
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    b, _, h, d = q.shape
-    _, bs, n_kv, _ = k_pages.shape
-    m = block_tables.shape[1]
-    g = h // n_kv
-
-    kernel = functools.partial(_paged_kernel_q8, block_s=bs, n_blocks=m,
-                               scale=d ** -0.5)
-    qg = q.reshape(b, 1, n_kv, g, d)
-    kv_spec = pl.BlockSpec(
-        (1, bs, 1, d),
-        lambda ib, ih, ik, len_ref, tbl_ref: (tbl_ref[ib, ik], 0, ih, 0))
-    sc_spec = pl.BlockSpec(
-        (1, bs, 1, 1),
-        lambda ib, ih, ik, len_ref, tbl_ref: (tbl_ref[ib, ik], 0, ih, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_kv, m),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, g, d),
-                         lambda ib, ih, ik, *_: (ib, 0, ih, 0, 0)),
-            kv_spec, kv_spec, sc_spec, sc_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda ib, ih, ik, *_: (ib, ih, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(cache_len.astype(jnp.int32), block_tables.astype(jnp.int32),
-      qg, k_pages, v_pages, k_scale, v_scale)
-    return out.reshape(b, 1, h, d)
+    """int8-KV paged variant: codes + per-row scales stream per physical
+    block and dequantize in VMEM (1 byte/element over the wire)."""
+    return _decode_call(q, (k_pages, v_pages, k_scale, v_scale), cache_len,
+                        block_tables, block_s=k_pages.shape[1],
+                        n_blocks=block_tables.shape[1], window=None,
+                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -317,45 +199,12 @@ def decode_attention_quant_pallas(
     cache_len: jax.Array,  # (B,) int32
     *,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    b, _, h, d = q.shape
-    _, s, n_kv, _ = k_cache.shape
-    g = h // n_kv
-    block_s = min(block_s, s)
-    if s % block_s:
-        raise ValueError("cache length must divide block_s")
-    ns = s // block_s
-
-    kernel = functools.partial(_kernel_q8, block_s=block_s, n_blocks=ns,
-                               scale=d ** -0.5)
-    qg = q.reshape(b, 1, n_kv, g, d)
-    kv_spec = pl.BlockSpec((1, block_s, 1, d),
-                           lambda ib, ih, ik: (ib, ik, ih, 0))
-    sc_spec = pl.BlockSpec((1, block_s, 1, 1),
-                           lambda ib, ih, ik: (ib, ik, ih, 0))
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, n_kv, ns),
-        in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, ik: (ib,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, 1, g, d), lambda ib, ih, ik: (ib, 0, ih, 0, 0)),
-            kv_spec, kv_spec, sc_spec, sc_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(cache_len.astype(jnp.int32), qg, k_cache, v_cache, k_scale, v_scale)
-    return out.reshape(b, 1, h, d)
+    block_s, ns = _dense_blocks(k_cache.shape[1], block_s)
+    return _decode_call(q, (k_cache, v_cache, k_scale, v_scale), cache_len,
+                        None, block_s=block_s, n_blocks=ns, window=None,
+                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_s", "interpret"))
@@ -367,39 +216,9 @@ def decode_attention_pallas(
     *,
     window: Optional[int] = None,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    b, _, h, d = q.shape
-    _, s, n_kv, _ = k_cache.shape
-    g = h // n_kv
-    block_s = min(block_s, s)
-    if s % block_s:
-        raise ValueError("cache length must divide block_s")
-    ns = s // block_s
-
-    kernel = functools.partial(_kernel, block_s=block_s, n_blocks=ns,
-                               window=window, scale=d ** -0.5)
-    qg = q.reshape(b, 1, n_kv, g, d)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, n_kv, ns),
-        in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, ik: (ib,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, 1, g, d), lambda ib, ih, ik: (ib, 0, ih, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda ib, ih, ik: (ib, ik, ih, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda ib, ih, ik: (ib, ik, ih, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(cache_len.astype(jnp.int32), qg, k_cache, v_cache)
-    return out.reshape(b, 1, h, d)
+    block_s, ns = _dense_blocks(k_cache.shape[1], block_s)
+    return _decode_call(q, (k_cache, v_cache), cache_len, None,
+                        block_s=block_s, n_blocks=ns, window=window,
+                        interpret=interpret)

@@ -11,7 +11,9 @@ Causal/sliding-window masking is applied per element; fully-masked kv
 blocks are skipped with ``pl.when`` so the causal lower triangle costs
 ~half the full-attention FLOPs.
 
-Validated on CPU in interpret mode against ``ref.mha_reference``.
+Validated on CPU in interpret mode against ``ref.mha_reference``. The v5e
+compiler refuses it: a block streams one head, (1, d) over the array's (H,
+d) trailing dims, which Mosaic cannot tile; ``ops`` raises on a TPU instead.
 """
 
 from __future__ import annotations
@@ -24,10 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases;
-# accept either so the kernels run on whichever toolchain is baked in.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -101,7 +100,7 @@ def flash_attention_pallas(
     q_offset: int = 0,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,  # CPU container: interpret; on TPU pass False
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, sk, n_kv, _ = k.shape
@@ -135,7 +134,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

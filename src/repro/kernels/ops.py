@@ -1,8 +1,10 @@
 """Attention / scan ops: jit'd wrappers that dispatch to an implementation.
 
 Backends:
-  * ``pallas`` — the TPU kernels in this package (``pl.pallas_call``); on CPU
-    they run in interpret mode (tests only — slow).
+  * ``pallas`` — the TPU kernels in this package (``pl.pallas_call``),
+    compiled on a TPU and interpreted elsewhere (tests only — slow; see
+    ``repro.kernels.interpret_mode``).  The flash, wkv6 and ssm kernels
+    are refused by the v5e compiler, so those ops raise on a TPU.
   * ``xla``    — pure-jnp *chunked* implementations with online softmax.
     Memory-bounded like the kernels (never materializes S x S), compiles to
     compact While-loop HLO, and is the default path inside the models.
@@ -23,6 +25,16 @@ import jax.numpy as jnp
 from repro.kernels import ref as _ref
 
 DEFAULT_BACKEND = "xla"
+
+# Pallas kernels the v5e compiler refuses.  Each streams one head per
+# block, (1, block, 1, d) over a (B, S, H, d) array, and Mosaic requires a
+# block's last two dims to be (8, 128)-aligned or the array's own.  The
+# decode kernels keep the whole KV-head axis per block for this reason.
+def _refuse_on_tpu(op: str) -> None:
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"{op}: the TPU compiler refuses its Pallas kernel (a one-head "
+            f"(1, d) block over an (H, d) array); use backend='xla'")
 
 NEG_INF = -1e30
 
@@ -64,6 +76,7 @@ def flash_attention(
         return _ref.mha_reference(q, k, v, causal=causal, window=window,
                                   q_offset=q_offset)
     if backend == "pallas":
+        _refuse_on_tpu("flash_attention")
         from repro.kernels import flash_attention as _fa
         return _fa.flash_attention_pallas(q, k, v, causal=causal,
                                           window=window, q_offset=q_offset,
@@ -490,6 +503,7 @@ def wkv6_scan(
 ) -> tuple[jax.Array, jax.Array]:
     """RWKV-6 WKV recurrence. Returns (out (B,S,H,D), new state)."""
     if backend == "pallas":
+        _refuse_on_tpu("wkv6_scan")
         from repro.kernels import wkv6 as _wkv
         return _wkv.wkv6_pallas(r, k, v, w, u, state)
     if backend == "ref":
@@ -525,6 +539,7 @@ def ssm_scan(
 ) -> tuple[jax.Array, jax.Array]:
     """Mamba-style selective scan (Hymba SSM heads)."""
     if backend == "pallas":
+        _refuse_on_tpu("ssm_scan")
         from repro.kernels import ssm_scan as _ssm
         return _ssm.ssm_scan_pallas(x, dt, a_log, b, c, state)
     if backend == "ref":

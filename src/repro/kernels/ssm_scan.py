@@ -5,22 +5,22 @@ across the sequential time axis.  Per step: elementwise decay
 ``exp(dt * A)`` on the (1 x N) row, a rank-1 (D x N) state update, and a
 (D x N) x (N,) contraction for the output — all VPU-friendly shapes.
 
-Validated in interpret mode against ``ref.ssm_reference``.
+Validated in interpret mode against ``ref.ssm_reference``. The v5e compiler
+refuses it: a block streams one head, (1, d) over the array's (H, d)
+trailing dims, which Mosaic cannot tile; ``ops`` raises on a TPU instead.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases;
-# accept either so the kernels run on whichever toolchain is baked in.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from repro.kernels import interpret_mode
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, sT_ref,
@@ -66,7 +66,7 @@ def ssm_scan_pallas(
     state: jax.Array,  # (B, H, D, N)
     *,
     block_t: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> tuple[jax.Array, jax.Array]:
     bsz, s, h, d = x.shape
     n = a_log.shape[-1]
@@ -96,8 +96,8 @@ def ssm_scan_pallas(
             jax.ShapeDtypeStruct((bsz, h, d, n), state.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((d, n), jnp.float32)],
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, a_log, b, c, state)
     return y, s_t

@@ -9,22 +9,22 @@ loop is a ``fori_loop`` over VMEM-resident tiles: each step is a (1 x D) x
 this replaces the CUDA warp-per-channel formulation of the reference
 implementation (DESIGN.md: hardware adaptation).
 
-Validated in interpret mode against ``ref.wkv6_reference``.
+Validated in interpret mode against ``ref.wkv6_reference``. The v5e compiler
+refuses it: a block streams one head, (1, d) over the array's (H, d)
+trailing dims, which Mosaic cannot tile; ``ops`` raises on a TPU instead.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x releases;
-# accept either so the kernels run on whichever toolchain is baked in.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+from repro.kernels import interpret_mode
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref,
@@ -70,7 +70,7 @@ def wkv6_pallas(
     state: jax.Array,  # (B, H, D, D)
     *,
     block_t: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> tuple[jax.Array, jax.Array]:
     b, s, h, d = r.shape
     block_t = min(block_t, s)
@@ -98,8 +98,8 @@ def wkv6_pallas(
             jax.ShapeDtypeStruct((b, h, d, d), state.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=_CompilerParams(dimension_semantics=(
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(r, k, v, w, u, state)
     return out, s_t

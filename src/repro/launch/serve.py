@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.model_sharing import pytree_nbytes
 from repro.core.resources import Alloc
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import ServingEngine
 
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--window", type=float, default=0.25)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     archs = args.arch or ["qwen2-7b"]
 
     engine = ServingEngine(window=args.window)

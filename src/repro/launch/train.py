@@ -18,6 +18,7 @@ import dataclasses
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models.config import ModelConfig
 from repro.training import AdamW, TrainStepConfig
@@ -52,6 +53,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.preset:
         cfg = PRESETS[args.preset]

@@ -20,10 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_mesh, named
@@ -177,7 +174,7 @@ def _moe_apply_shardmap(params: dict, x: jax.Array, cfg: ModelConfig,
                      P(None, None)]
     fn = shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
                    out_specs=(P(dp_spec, None, None), P()),
-                   check_rep=False)
+                   check_vma=False)
     return fn(*args)
 
 

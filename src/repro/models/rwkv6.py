@@ -3,7 +3,8 @@
 Per layer: a TimeMix block (token-shift ddlerp for r/k/v/w/g, low-rank
 data-dependent decay, WKV recurrence with per-head state) and a ChannelMix
 block (token-shift, squared-relu FFN).  The WKV recurrence runs through
-``repro.kernels.ops.wkv6_scan`` (Pallas kernel on TPU, scan on CPU).
+``repro.kernels.ops.wkv6_scan`` on its XLA ``lax.scan`` path, on every
+platform (the Pallas kernel is refused by the v5e compiler).
 
 Decode state per layer: (tm_x (B,D), cm_x (B,D), wkv (B,H,Dh,Dh)) — O(1) in
 sequence length, which is why rwkv6 runs the long_500k cell.

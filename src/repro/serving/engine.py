@@ -53,6 +53,7 @@ end.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import deque
@@ -104,6 +105,19 @@ def _executor(model: Model, key: tuple, build) -> Any:
 
 # Model-independent: scatter one sampled token into the donated vector.
 _SET_TOK = jax.jit(lambda t, s, v: t.at[s].set(v), donate_argnums=(0,))
+
+
+def _on_device(method):
+    """Run an instance method with its node's device as JAX's default, so
+    every array the step uploads (prompts, tables, positions, a fresh KV
+    pool) lands next to the weights instead of on ``jax.devices()[0]``."""
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        if self.device is None:
+            return method(self, *args, **kwargs)
+        with jax.default_device(self.device):
+            return method(self, *args, **kwargs)
+    return wrapper
 
 
 def per_device_bytes(*trees: Any) -> dict[int, int]:
@@ -172,7 +186,8 @@ class FunctionInstance:
                  speculate: Optional[SpecConfig] = None,
                  draft_model: Optional[Model] = None,
                  draft_key: Optional[str] = None,
-                 mesh: Optional[Any] = None):
+                 mesh: Optional[Any] = None,
+                 device: Optional[Any] = None):
         if batching not in ("continuous", "static", "paged"):
             raise ValueError(f"unknown batching mode {batching!r}")
         if sampling is not None and batching == "static":
@@ -188,6 +203,9 @@ class FunctionInstance:
         # shards=1 instance hits the exact single-device cache entries —
         # no re-trace, byte-identical dispatch.
         self.mesh = mesh
+        # The node's device (None: JAX's default): uploads and the KV pool
+        # go there.  A sharded pod places by its mesh instead.
+        self.device = device if mesh is None else None
         self._mkey = (() if mesh is None else
                       ("tp", tuple(int(d.id) for d in mesh.devices.flat)))
 
@@ -332,7 +350,8 @@ class FunctionInstance:
         self._key_dev: Optional[jax.Array] = None
         if sampling is not None or speculate is not None:
             seed = sampling.seed if sampling is not None else speculate.seed
-            self._key_dev = jax.random.PRNGKey(seed)
+            self._key_dev = jax.device_put(jax.random.PRNGKey(seed),
+                                           self.device)
         if sampling is not None:
             self._sample = _jit(
                 model, ("sample", sampling),
@@ -878,6 +897,7 @@ class FunctionInstance:
         self._slot_tok_dev = tok  # device-resident input of the next round
         self._round = (tok, active)
 
+    @_on_device
     def dispatch_step(self) -> bool:
         """Dispatch one token-gated step WITHOUT any host synchronisation.
 
@@ -985,6 +1005,7 @@ class FunctionInstance:
 
     # -- migration seam (pause -> gather -> merge) --------------------------
 
+    @_on_device
     def export_slot(self, slot: int) -> tuple[ServeRequest, Any, int]:
         """Gather one occupied slot's full decode state for migration:
         ``(request, batch-1 cache entry, last emitted token)``.
@@ -1010,6 +1031,7 @@ class FunctionInstance:
             entry = self.model.gather_slot(self.cache, jnp.int32(slot))
         return req, entry, int(self._slot_tok[slot])
 
+    @_on_device
     def import_slot(self, slot: int, req: ServeRequest, entry: Any,
                     tok: int) -> None:
         """Merge an exported slot into this instance at ``slot`` — the
@@ -1020,6 +1042,8 @@ class FunctionInstance:
         if self.slots[slot] is not None:
             raise ValueError(f"slot {slot} of {self.inst_id} is occupied")
         paged = self.batching == "paged"
+        # The entry was gathered on the source node's device.
+        entry = jax.device_put(entry, self.device)
         if self.cache is None:
             self.cache = self._init_cache()
         if paged:
@@ -1126,9 +1150,14 @@ class FunctionInstance:
 
 
 class ServingEngine:
-    """One node: token scheduler + N weight-shared instances."""
+    """One node: token scheduler + N weight-shared instances.
 
-    def __init__(self, window: float = 0.2, idle_sleep_s: float = 0.001):
+    ``device`` is the node's accelerator: its weights and KV pools live
+    there (None keeps JAX's default device, as on a one-device host)."""
+
+    def __init__(self, window: float = 0.2, idle_sleep_s: float = 0.001,
+                 device: Optional[Any] = None):
+        self.device = device
         self.scheduler = TokenScheduler(window=window)
         self.store = ModelStore()
         self.instances: dict[str, FunctionInstance] = {}
@@ -1185,6 +1214,8 @@ class ServingEngine:
             if mesh is not None:
                 params = shard_put(params, model.param_names(), mesh,
                                    resolver=serve_pspec)
+            else:
+                params = jax.device_put(params, self.device)
             self.store.store(weights_key, params)
         draft_model = None
         draft_key = None
@@ -1202,7 +1233,8 @@ class ServingEngine:
                     raise ValueError(
                         f"{fn}: speculate set but no draft weights staged "
                         f"(pass draft_params on the first deploy)")
-                self.store.store(draft_key, draft_params)
+                self.store.store(draft_key,
+                                 jax.device_put(draft_params, self.device))
         ids = []
         for _ in range(n_instances):
             inst_id = f"{fn}/{next(self._inst_seq)}"
@@ -1216,7 +1248,8 @@ class ServingEngine:
                                     prefix_sharing=prefix_sharing,
                                     sampling=sampling, speculate=speculate,
                                     draft_model=draft_model,
-                                    draft_key=draft_key, mesh=mesh)
+                                    draft_key=draft_key, mesh=mesh,
+                                    device=self.device)
             self.instances[inst_id] = inst
             self.scheduler.register(inst_id, alloc)
             ids.append(inst_id)

@@ -56,6 +56,32 @@ from repro.serving.paging import blocks_needed
 # bookkeeping) charged by admission when the caller gives no measurement.
 DEFAULT_FRAMEWORK_BYTES = 64 * 1024 * 1024
 
+# Admission capacity of a node whose device reports no memory limit (the
+# CPU backend): the HBM of one 16 GiB accelerator.
+HOST_MEM_BYTES = 16 * 1024**3
+
+
+def node_devices(n_nodes: int) -> list[Optional[Any]]:
+    """Node i runs on ``jax.devices()[i]`` when the host has that many
+    devices; otherwise every node shares JAX's default device (None)."""
+    devices = jax.devices()
+    if n_nodes <= len(devices):
+        return list(devices[:n_nodes])
+    return [None] * n_nodes
+
+
+def device_mem_bytes(devices: list[Optional[Any]]) -> int:
+    """Per-node admission capacity: the smallest ``bytes_limit`` the nodes'
+    devices report (what the runtime lets a program allocate — 15.75 GB on
+    a 16 GB v5e), or ``HOST_MEM_BYTES`` when a device reports none."""
+    limits = []
+    for d in devices:
+        stats = (d if d is not None else jax.devices()[0]).memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return HOST_MEM_BYTES
+        limits.append(int(stats["bytes_limit"]))
+    return min(limits)
+
 
 @dataclasses.dataclass
 class InstancePlacement:
@@ -85,7 +111,7 @@ class ClusterFrontend:
     """Join-shortest-queue router over N token-scheduled engine nodes."""
 
     def __init__(self, n_nodes: int = 2, *,
-                 mem_bytes: int = 16 * 1024**3, window: float = 0.2,
+                 mem_bytes: Optional[int] = None, window: float = 0.2,
                  model_store: Optional[FleetModelStore] = None,
                  cold_start: str = "overlap",
                  links: Optional[NetworkLinks] = None,
@@ -113,14 +139,18 @@ class ClusterFrontend:
         # (event, node, inst_id): TTFT resolved lazily from the instance's
         # first landed token by cold_start_events().
         self._cold_instances: list[tuple[ColdStartEvent, int, str]] = []
+        devices = node_devices(n_nodes)
         self.engines = [ServingEngine(window=window,
-                                      idle_sleep_s=idle_sleep_s)
-                        for _ in range(n_nodes)]
+                                      idle_sleep_s=idle_sleep_s, device=d)
+                        for d in devices]
         for i, eng in enumerate(self.engines):
             eng.on_instance_closed = functools.partial(
                 self._instance_closed, i)
         self.pool = MaxRectsPool(n_nodes, allow_grow=False)
-        self.mem_bytes = mem_bytes
+        # Per-node memory admission budget; by default what the nodes'
+        # devices report they can hold.
+        self.mem_bytes = (mem_bytes if mem_bytes is not None
+                          else device_mem_bytes(devices))
         self.placements: list[InstancePlacement] = []
         self._fn_mm: dict[str, MemoryModel] = {}
         self._pod_seq = itertools.count()

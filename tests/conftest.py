@@ -274,3 +274,17 @@ def tiny_params(tiny_model):
     import jax
 
     return tiny_model.init(jax.random.key(TINY_SEED))
+
+
+@pytest.fixture(scope="session")
+def smoke():
+    """The repo-root ``chip_smoke.py`` script, imported as a module."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = module  # dataclasses resolve it by name
+    spec.loader.exec_module(module)
+    return module
