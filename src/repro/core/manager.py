@@ -48,11 +48,11 @@ class _PodState:
     occupied_sm: float = 0.0  # effective occupancy while holding a token
     q_used: float = 0.0  # seconds of accelerator time used this window
     wants_token: bool = False
+    requested_at: float = 0.0  # when the pending token request was made
     holding: Optional[Token] = None
-    # lifetime accounting
-    total_busy: float = 0.0
-    tokens_granted: int = 0
-    blocked_rounds: int = 0
+    # Seconds from each token request to its grant, summed: the time the
+    # pod was ready to run but held back by its quota or the SM limit.
+    token_wait_s: float = 0.0
 
     def q_miss(self, window: float) -> float:
         return self.alloc.quota_request * window - self.q_used
@@ -119,8 +119,14 @@ class TokenScheduler:
     # -- frontend hook: token request / completion ------------------------
 
     def request_token(self, pod_id: str, now: float) -> None:
+        """The pod wants to launch a step.  Repeating the request before
+        it is granted keeps its first time, so ``token_wait_s`` runs from
+        the first request to the grant."""
         self._maybe_roll(now)
-        self.pods[pod_id].wants_token = True
+        st = self.pods[pod_id]
+        if not st.wants_token:
+            st.wants_token = True
+            st.requested_at = now
 
     def complete(self, pod_id: str, elapsed: float, now: float,
                  occ: Optional[float] = None) -> None:
@@ -134,7 +140,6 @@ class TokenScheduler:
         if state.holding is None:
             raise RuntimeError(f"pod {pod_id} completed without a token")
         state.q_used += elapsed
-        state.total_busy += elapsed
         self._stats.busy_time += elapsed
         self._stats.busy_area += elapsed * (
             state.holding.occ if occ is None else occ)
@@ -156,8 +161,7 @@ class TokenScheduler:
             if not st.wants_token or st.holding is not None:
                 continue
             if st.q_remain(self.window) <= 0:
-                st.blocked_rounds += 1  # blocked until next window (e.g. F3)
-                continue
+                continue  # blocked until the next window (e.g. F3)
             ready.append((st.q_miss(self.window), pod_id))
         # 2. Ready-function priority queue: descending Q_miss.
         ready.sort(key=lambda t: (-t[0], t[1]))
@@ -174,7 +178,7 @@ class TokenScheduler:
                           occ=st.occupied_sm)
             st.holding = token
             st.wants_token = False
-            st.tokens_granted += 1
+            st.token_wait_s += now - st.requested_at
             s_running += st.alloc.sm
             granted.append(token)
             if self.on_grant:
@@ -211,31 +215,12 @@ class TokenScheduler:
             return 0.0
         return sum(w.busy_union for w in hist) / (len(hist) * self.window)
 
-    def temporal_load(self, last_n: int = 10) -> float:
-        """Σ token-held seconds / window — the uncapped concurrency load."""
-        hist = self.stats_history[-last_n:]
-        if not hist:
-            return 0.0
-        return sum(w.busy_time for w in hist) / (len(hist) * self.window)
-
     def occupancy(self, last_n: int = 10) -> float:
         """SM occupancy: busy-area / window (spatial x temporal product)."""
         hist = self.stats_history[-last_n:]
         if not hist:
             return 0.0
         return sum(w.busy_area for w in hist) / (len(hist) * self.window)
-
-    def isolation_error(self, pod_id: str, last_n: int = 10) -> float:
-        """|delivered - entitled| quota over recent windows, for isolation tests."""
-        st = self.pods[pod_id]
-        hist = self.stats_history[-last_n:]
-        if not hist:
-            return 0.0
-        entitled = st.alloc.quota_limit * len(hist) * self.window
-        # Delivered time is tracked per-pod only in total_busy; scope it by
-        # assuming steady registration (tests use dedicated schedulers).
-        delivered = st.total_busy
-        return max(0.0, delivered - entitled) / max(entitled, 1e-9)
 
 
 def fair_share_baseline(allocs: dict[str, Alloc], window: float = 1.0) -> dict[str, float]:

@@ -57,13 +57,14 @@ import functools
 import itertools
 import time
 from collections import deque
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.core.manager import TokenScheduler
+from repro.core.manager import Token, TokenScheduler
 from repro.core.model_sharing import ModelStore
 from repro.core.resources import Alloc
 from repro.core.slo import SLORecorder
@@ -81,7 +82,7 @@ def _bucket_len(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
 
 
-def _executor(model: Model, key: tuple, build) -> Any:
+def _executor(model: Model, key: tuple, fn, **jit_kw) -> Any:
     """Per-model shared jit wrapper: ``jax.jit`` caches compiled
     executables per *wrapper object*, so per-instance wrappers would pay
     a fresh trace + compile on every deployment.  Sharing them across
@@ -90,21 +91,29 @@ def _executor(model: Model, key: tuple, build) -> Any:
     the function's compiled executors, not just its weights.  Donation
     is per-call semantics, so shared donated wrappers are safe.
 
-    ``build`` must jit a FRESH function object (a lambda), never a bound
-    method directly: jax shares its trace cache across jit wrappers of
-    the same underlying function, and a sharded pod's mesh constraints
-    are baked into the jaxpr at trace time — a bound-method trace from
-    one device group would silently serve every other group's executor
-    and fail on the first mismatched device set."""
+    ``fn`` must be a FRESH function object (a lambda or closure), never a
+    bound method: jax shares its trace cache across jit wrappers of the
+    same underlying function, and a sharded pod's mesh constraints are
+    baked into the jaxpr at trace time — a bound-method trace from one
+    device group would silently serve every other group's executor and
+    fail on the first mismatched device set.  It is named ``key[0]``
+    before it is jitted, so its compiled module (and every device op of
+    it in a profiler trace) reads ``jit_<key[0]>``."""
     cache = model.__dict__.setdefault("_jit_executors", {})
-    fn = cache.get(key)
-    if fn is None:
-        fn = cache[key] = build()
-    return fn
+    exe = cache.get(key)
+    if exe is None:
+        fn.__name__ = fn.__qualname__ = key[0]
+        exe = cache[key] = jax.jit(fn, **jit_kw)
+    return exe
 
 
-# Model-independent: scatter one sampled token into the donated vector.
-_SET_TOK = jax.jit(lambda t, s, v: t.at[s].set(v), donate_argnums=(0,))
+def set_tok(t, s, v):
+    """Scatter one sampled token into the donated slot-token vector."""
+    return t.at[s].set(v)
+
+
+# Model-independent, so one wrapper for every instance.
+_SET_TOK = jax.jit(set_tok, donate_argnums=(0,))
 
 
 def _on_device(method):
@@ -148,6 +157,9 @@ class ServeRequest:
     tokens_out: list = dataclasses.field(default_factory=list)
     done: bool = False
     finished_at: float = 0.0
+    # When its prefill was first dispatched (the clock of submitted_at);
+    # a re-routed or re-executed request keeps its first admission.
+    admitted_at: Optional[float] = None
     # SLO lifecycle (inert by default): absolute deadline on the engine
     # clock (None = no deadline), the tier it was admitted under, a typed
     # outcome when the request terminates without completing
@@ -174,6 +186,9 @@ class FunctionInstance:
     ``run_step`` chains the two for callers that want the old synchronous
     protocol.  ``fused=False`` restores the host-side argmax reference —
     token streams are bit-identical either way.
+
+    ``clock`` stamps ``ServeRequest.admitted_at``; the engine passes its
+    own, the clock of ``submitted_at``.
     """
 
     def __init__(self, inst_id: str, model: Model, store: ModelStore,
@@ -187,7 +202,8 @@ class FunctionInstance:
                  draft_model: Optional[Model] = None,
                  draft_key: Optional[str] = None,
                  mesh: Optional[Any] = None,
-                 device: Optional[Any] = None):
+                 device: Optional[Any] = None,
+                 clock: Callable[[], float] = time.perf_counter):
         if batching not in ("continuous", "static", "paged"):
             raise ValueError(f"unknown batching mode {batching!r}")
         if sampling is not None and batching == "static":
@@ -209,17 +225,18 @@ class FunctionInstance:
         self._mkey = (() if mesh is None else
                       ("tp", tuple(int(d.id) for d in mesh.devices.flat)))
 
-        def _jit(owner: Model, key: tuple, build) -> Any:
-            fn = _executor(owner, key + self._mkey, build)
+        def _jit(owner: Model, key: tuple, fn, **jit_kw) -> Any:
+            exe = _executor(owner, key + self._mkey, fn, **jit_kw)
             if mesh is None:
-                return fn
+                return exe
 
-            def sharded(*a, _fn=fn, **kw):
+            def sharded(*a, _exe=exe, **kw):
                 with use_mesh(mesh):
-                    return _fn(*a, **kw)
+                    return _exe(*a, **kw)
             return sharded
 
         self.inst_id = inst_id
+        self.clock = clock
         self.model = model
         self.alloc = alloc
         self.max_batch = max_batch
@@ -230,39 +247,37 @@ class FunctionInstance:
         self.weights_key = weights_key
         self.params = store.get(weights_key)  # shared, zero-copy
         self.queue: deque[ServeRequest] = deque()
-        self._prefill = _jit(model, ("prefill", max_len), lambda:
-                                  jax.jit(lambda p, t: model.prefill(
-                                      p, t, max_len=max_len)))
+        self._prefill = _jit(model, ("prefill", max_len),
+                             lambda p, t: model.prefill(p, t,
+                                                        max_len=max_len))
         # Bucketed chunked admission: prompts are right-padded to power-of-
         # two buckets so the jitted prefill sees O(log max_len) distinct
         # shapes instead of one per prompt length (each a recompile).
         self.bucketed = (batching in ("continuous", "paged")
                          and prefill_buckets
                          and model.supports_bucketed_prefill())
-        self._prefill_len = _jit(model, ("prefill_len", max_len),
-                                      lambda: jax.jit(
-                                          lambda p, t, n: model.prefill(
-                                              p, t, max_len=max_len,
-                                              length=n))
-                                      ) if self.bucketed else None
+        self._prefill_len = _jit(
+            model, ("prefill_len", max_len),
+            lambda p, t, n: model.prefill(p, t, max_len=max_len, length=n)
+        ) if self.bucketed else None
         self._decode = _jit(model, ("decode",),
-                                 lambda: jax.jit(lambda *a: model.decode_step(*a)))
+                            lambda *a: model.decode_step(*a))
         # Fused executors: the decode round samples on device and returns
         # (B,) int32 tokens; the token vector and the whole KV pool are
         # DONATED — after dispatch the old buffers are dead and XLA writes
         # the new round in place (no per-round cache copy).  Never alias a
         # donated buffer after dispatch (serving/README.md "Hot path").
-        self._decode_tok = _jit(model, ("decode_tok",), lambda:
-                                     jax.jit(lambda *a: model.decode_step_tokens(*a),
-                                             donate_argnums=(1, 2)))
+        self._decode_tok = _jit(model, ("decode_tok",),
+                                lambda *a: model.decode_step_tokens(*a),
+                                donate_argnums=(1, 2))
         self._greedy = _jit(model, ("greedy",),
-                                 lambda: jax.jit(lambda *a: model.sample_greedy(*a)))
+                            lambda *a: model.sample_greedy(*a))
         self._set_tok = _SET_TOK
         # The slot pool is donated on merge/append too: admitting a request
         # scatters its prefill entry into the pool in place.
-        self._merge = _jit(model, ("merge",), lambda:
-                                jax.jit(lambda *a: model.merge_slot(*a),
-                                        donate_argnums=(0,)))
+        self._merge = _jit(model, ("merge",),
+                           lambda *a: model.merge_slot(*a),
+                           donate_argnums=(0,))
         self.steps = 0
         self.retired = False  # draining: no new routing, slots finish
         self.paused = False   # migrating: no admission, no decode
@@ -318,17 +333,17 @@ class FunctionInstance:
             self._pos = np.zeros((max_batch,), np.int32)
             self._decode_paged = _jit(
                 model, ("decode_paged",),
-                lambda: jax.jit(lambda *a: model.decode_step_paged(*a)))
+                lambda *a: model.decode_step_paged(*a))
             self._decode_paged_tok = _jit(
                 model, ("decode_paged_tok",),
-                lambda: jax.jit(lambda *a: model.decode_step_paged_tokens(*a),
-                                donate_argnums=(1, 2, 4)))
-            self._append = _jit(
-                model, ("append",),
-                lambda: jax.jit(lambda *a: model.append_paged(*a), donate_argnums=(0,)))
-            self._copy_block = _jit(
-                model, ("copy_block",),
-                lambda: jax.jit(lambda *a: model.copy_block(*a), donate_argnums=(0,)))
+                lambda *a: model.decode_step_paged_tokens(*a),
+                donate_argnums=(1, 2, 4))
+            self._append = _jit(model, ("append",),
+                                lambda *a: model.append_paged(*a),
+                                donate_argnums=(0,))
+            self._copy_block = _jit(model, ("copy_block",),
+                                    lambda *a: model.copy_block(*a),
+                                    donate_argnums=(0,))
             self._tables_dev: Optional[jax.Array] = None
             self._pos_dev: Optional[jax.Array] = None
             self._active_dev: Optional[jax.Array] = None
@@ -355,22 +370,19 @@ class FunctionInstance:
         if sampling is not None:
             self._sample = _jit(
                 model, ("sample", sampling),
-                lambda: jax.jit(lambda l, k: model.sample_tokens(l, k,
-                                                                 sampling)))
+                lambda l, k: model.sample_tokens(l, k, sampling))
             self._decode_tok_s = _jit(
                 model, ("decode_tok_sampled", sampling),
-                lambda: jax.jit(
-                    lambda p, t, c, k: model.decode_step_tokens(
-                        p, t, c, key=k, sampling=sampling),
-                    donate_argnums=(1, 2, 3)))
+                lambda p, t, c, k: model.decode_step_tokens(
+                    p, t, c, key=k, sampling=sampling),
+                donate_argnums=(1, 2, 3))
             if batching == "paged":
                 self._decode_paged_tok_s = _jit(
                     model, ("decode_paged_tok_sampled", sampling),
-                    lambda: jax.jit(
-                        lambda p, t, c, tb, pos, act, k:
-                        model.decode_step_paged_tokens(
-                            p, t, c, tb, pos, act, key=k, sampling=sampling),
-                        donate_argnums=(1, 2, 4, 6)))
+                    lambda p, t, c, tb, pos, act, k:
+                    model.decode_step_paged_tokens(
+                        p, t, c, tb, pos, act, key=k, sampling=sampling),
+                    donate_argnums=(1, 2, 4, 6))
         if speculate is not None:
             if not self.fused or batching == "static":
                 raise ValueError(
@@ -397,20 +409,19 @@ class FunctionInstance:
             self._spec_round = _jit(
                 model, ("spec_round", batching, speculate.k, samp,
                         draft_model.cfg.name),
-                lambda: jax.jit(build(model, draft_model, speculate.k, samp),
-                                donate_argnums=donate))
+                build(model, draft_model, speculate.k, samp),
+                donate_argnums=donate)
             self._dprefill = _jit(
                 draft_model, ("prefill", max_len),
-                lambda: jax.jit(lambda p, t: draft_model.prefill(
-                    p, t, max_len=max_len)))
+                lambda p, t: draft_model.prefill(p, t, max_len=max_len))
             self._dprefill_len = _jit(
                 draft_model, ("prefill_len", max_len),
-                lambda: jax.jit(lambda p, t, n: draft_model.prefill(
-                    p, t, max_len=max_len, length=n))
+                lambda p, t, n: draft_model.prefill(p, t, max_len=max_len,
+                                                    length=n)
             ) if self.bucketed else None
-            self._dmerge = _jit(
-                draft_model, ("merge",),
-                lambda: jax.jit(draft_model.merge_slot, donate_argnums=(0,)))
+            self._dmerge = _jit(draft_model, ("merge",),
+                                lambda *a: draft_model.merge_slot(*a),
+                                donate_argnums=(0,))
 
     def close(self) -> None:
         if self.batching == "paged":
@@ -534,35 +545,31 @@ class FunctionInstance:
 
     # -- continuous path ---------------------------------------------------
 
-    def _prefill_one(self, prompt: np.ndarray):
+    def _prefill_rows(self, n: int) -> int:
+        """The length an ``n``-token prompt's prefill runs at: its bucket
+        when bucketing applies, else ``n``."""
+        if self.bucketed and n < self.max_len:
+            return min(_bucket_len(n), self.max_len)
+        return n
+
+    def _run_prefill(self, exe, exe_len, params, prompt: np.ndarray):
         """Prefill one prompt, right-padded to its bucket when enabled."""
         n = int(prompt.shape[0])
         if self.bucketed and n < self.max_len:
-            pl = min(_bucket_len(n), self.max_len)
-            if pl > n:
-                padded = np.zeros((pl,), np.int32)
-                padded[:n] = prompt
-                prompt = padded
-            return self._prefill_len(self.params,
-                                     jnp.asarray(prompt[None], jnp.int32),
-                                     jnp.int32(n))
-        return self._prefill(self.params, jnp.asarray(prompt[None], jnp.int32))
+            padded = np.zeros((self._prefill_rows(n),), np.int32)
+            padded[:n] = prompt
+            return exe_len(params, jnp.asarray(padded[None]), jnp.int32(n))
+        return exe(params, jnp.asarray(prompt[None], jnp.int32))
+
+    def _prefill_one(self, prompt: np.ndarray):
+        return self._run_prefill(self._prefill, self._prefill_len,
+                                 self.params, prompt)
 
     def _dprefill_one(self, prompt: np.ndarray):
         """Draft-model prefill for speculative admission (same bucketing
         discipline as the target's)."""
-        n = int(prompt.shape[0])
-        if self.bucketed and n < self.max_len:
-            pl = min(_bucket_len(n), self.max_len)
-            if pl > n:
-                padded = np.zeros((pl,), np.int32)
-                padded[:n] = prompt
-                prompt = padded
-            return self._dprefill_len(self.draft_params,
-                                      jnp.asarray(prompt[None], jnp.int32),
-                                      jnp.int32(n))
-        return self._dprefill(self.draft_params,
-                              jnp.asarray(prompt[None], jnp.int32))
+        return self._run_prefill(self._dprefill, self._dprefill_len,
+                                 self.draft_params, prompt)
 
     def _admit_draft(self, slot: int, req: ServeRequest) -> None:
         """Prefill the draft model and merge its entry into the draft slot
@@ -676,60 +683,76 @@ class FunctionInstance:
             if self.slots[slot] is not None or not self.queue:
                 continue
             head = self.queue[0]
-            plan = ([], None, [], None)
-            if paged and head.max_new_tokens > 1:
-                need, plan = self._plan_paged_admission(head)
-                if not self.allocator.can_alloc(need):
-                    break  # head-of-line waits for retiring blocks
-            req = self.queue.popleft()
-            logits, entry = self._prefill_one(req.prompt)
-            if self.sampling is not None:
-                # Same eager split in the fused and reference paths, so the
-                # key stream (one split per admitted prefill, one per
-                # round) is identical and sampled streams diff
-                # bit-identical.  The split is async — no host pull.
-                self._key_dev, sub = jax.random.split(self._key_dev)
-                tok_dev = self._sample(logits, sub)
-            else:
-                tok_dev = self._greedy(logits)  # (1,) int32, stays on device
-            if self.fused:
-                done_at_prefill = (len(req.tokens_out) + 1
-                                   >= req.max_new_tokens)
-                self._pending_prefill.append(
-                    (req, tok_dev, None if done_at_prefill else slot))
-                if done_at_prefill:
-                    finished.append(req)  # completed by sync_step
-                    continue  # slot stays free for the next queued request
-            else:
-                self.sync_count += 1
-                tok = int(np.asarray(tok_dev)[0])
-                req.tokens_out.append(tok)
-                self._mark_first_token()
-                if len(req.tokens_out) >= req.max_new_tokens:
-                    req.done = True
-                    finished.append(req)
-                    continue
-            if self.cache is None:
-                self.cache = self._init_cache()
-            if had_live:
-                self.refills += 1  # joined a live decode batch mid-flight
-            if paged:
-                # Sequences are keyed by SLOT, not req_id: slots are unique
-                # within the instance and always released before reuse,
-                # whereas req_ids from different engines can collide when
-                # an evict re-routes queued requests across nodes.
-                self._map_paged_request(slot, req, entry, plan)
-            else:
-                self.cache = self._merge(self.cache, entry, jnp.int32(slot))
-            if self.speculate is not None:
-                self._admit_draft(slot, req)
-            self.slots[slot] = req
-            if self.fused:
-                self._slot_tok_dev = self._set_tok(
-                    self._tok_dev(), jnp.int32(slot), tok_dev[0])
-            else:
-                self._slot_tok[slot] = tok  # type: ignore[possibly-undefined]
+            n = int(head.prompt.shape[0])
+            with TraceAnnotation("serve.admit", req=head.req_id, prompt=n,
+                                 bucket=self._prefill_rows(n)) as span:
+                plan = ([], None, [], None)
+                if paged and head.max_new_tokens > 1:
+                    need, plan = self._plan_paged_admission(head)
+                    span.set_metadata(shared_blocks=len(plan[2]) + (
+                        plan[3] is not None))
+                    if not self.allocator.can_alloc(need):
+                        break  # head-of-line waits for retiring blocks
+                done = self._admit_one(slot, self.queue.popleft(), plan,
+                                       had_live)
+            if done is not None:
+                finished.append(done)
         return finished
+
+    def _admit_one(self, slot: int, req: ServeRequest, plan: tuple,
+                   had_live: bool) -> Optional[ServeRequest]:
+        """Dispatch ``req``'s prefill and, unless its prefill token
+        completes it, bind it to ``slot`` (paged: ``plan``'s pages).
+        Returns ``req`` iff the prefill completes it."""
+        if req.admitted_at is None:
+            req.admitted_at = self.clock()
+        logits, entry = self._prefill_one(req.prompt)
+        if self.sampling is not None:
+            # Same eager split in the fused and reference paths, so the
+            # key stream (one split per admitted prefill, one per round)
+            # is identical and sampled streams diff bit-identical.  The
+            # split is async — no host pull.
+            self._key_dev, sub = jax.random.split(self._key_dev)
+            tok_dev = self._sample(logits, sub)
+        else:
+            tok_dev = self._greedy(logits)  # (1,) int32, stays on device
+        if self.fused:
+            done_at_prefill = len(req.tokens_out) + 1 >= req.max_new_tokens
+            self._pending_prefill.append(
+                (req, tok_dev, None if done_at_prefill else slot))
+            if done_at_prefill:
+                # Completed by sync_step; the slot stays free for the next
+                # queued request.
+                return req
+        else:
+            self.sync_count += 1
+            tok = int(np.asarray(tok_dev)[0])
+            req.tokens_out.append(tok)
+            self._mark_first_token()
+            if len(req.tokens_out) >= req.max_new_tokens:
+                req.done = True
+                return req
+        if self.cache is None:
+            self.cache = self._init_cache()
+        if had_live:
+            self.refills += 1  # joined a live decode batch mid-flight
+        if self.batching == "paged":
+            # Sequences are keyed by SLOT, not req_id: slots are unique
+            # within the instance and always released before reuse,
+            # whereas req_ids from different engines can collide when an
+            # evict re-routes queued requests across nodes.
+            self._map_paged_request(slot, req, entry, plan)
+        else:
+            self.cache = self._merge(self.cache, entry, jnp.int32(slot))
+        if self.speculate is not None:
+            self._admit_draft(slot, req)
+        self.slots[slot] = req
+        if self.fused:
+            self._slot_tok_dev = self._set_tok(
+                self._tok_dev(), jnp.int32(slot), tok_dev[0])
+        else:
+            self._slot_tok[slot] = tok  # type: ignore[possibly-undefined]
+        return None
 
     def _advance_slot(self, slot: int, tok: int) -> Optional[ServeRequest]:
         """Land one decode round's token on an occupied slot: append it,
@@ -928,10 +951,12 @@ class FunctionInstance:
                 and self.n_active() > 0):
             # COW must resolve before this round's writes dispatch —
             # both the fused round below and the host-argmax reference.
-            self._cow_round()
+            with TraceAnnotation("serve.cow"):
+                self._cow_round()
         if self.fused:
             if self.n_active() > 0:
-                self._dispatch_round()
+                with TraceAnnotation("serve.dispatch"):
+                    self._dispatch_round()
             return True
         if self.n_active() > 0:
             finished += (self._decode_round_paged()
@@ -954,53 +979,52 @@ class FunctionInstance:
         if not self._pending_prefill and self._round is None:
             return []
         self.sync_count += 1  # the pass's single synchronisation point
-        arrays = [t for _, t, _ in self._pending_prefill]
-        if self._round is not None:
-            arrays.append(self._round[0])
-        if self._round_spec is not None:
-            arrays.extend(self._round_spec)
-        jax.block_until_ready(arrays)
-        finished = []
-        for req, tok_dev, slot in self._pending_prefill:
-            tok = int(np.asarray(tok_dev)[0])
-            req.tokens_out.append(tok)
-            self._mark_first_token()
-            if slot is None:  # whole request served by its prefill
-                req.done = True
-                finished.append(req)
-            else:
-                self._slot_tok[slot] = tok  # host mirror (migration seam)
-        self._pending_prefill = []
-        if self._round is not None and self._round_spec is not None:
-            # Speculative round: land the accepted window per slot.  A slot
-            # that reaches max_new mid-window is released immediately and
-            # its surplus tokens dropped — the device position overshot,
-            # but release resets the mirrors (paged: dirty re-upload;
-            # continuous: the next merge overwrites the slot's pos).
-            _, active = self._round
-            out_dev, n_dev = self._round_spec
-            self._round = self._round_spec = None
-            out_np = np.asarray(out_dev)
-            n_np = np.asarray(n_dev)
-            for slot in active:
-                n = int(n_np[slot])
-                self.spec_proposed += self.speculate.k
-                self.spec_accepted += n - 1
-                done = None
-                for t in out_np[slot, :n]:
-                    done = self._advance_slot(slot, int(t))
+        pending, self._pending_prefill = self._pending_prefill, []
+        rnd, spec = self._round, self._round_spec
+        self._round = self._round_spec = None
+        arrays = [t for _, t, _ in pending]
+        if rnd is not None:
+            arrays.append(rnd[0])
+        if spec is not None:
+            arrays.extend(spec)
+        with TraceAnnotation("serve.sync"):
+            jax.block_until_ready(arrays)
+            first = [int(np.asarray(t)[0]) for _, t, _ in pending]
+            if spec is not None:
+                out_np, n_np = np.asarray(spec[0]), np.asarray(spec[1])
+            elif rnd is not None:
+                toks = np.asarray(rnd[0])
+        with TraceAnnotation("serve.land"):
+            finished = []
+            for (req, _, slot), tok in zip(pending, first):
+                req.tokens_out.append(tok)
+                self._mark_first_token()
+                if slot is None:  # whole request served by its prefill
+                    req.done = True
+                    finished.append(req)
+                else:
+                    self._slot_tok[slot] = tok  # host mirror (migration)
+            if spec is not None:
+                # Speculative round: land the accepted window per slot.  A
+                # slot that reaches max_new mid-window is released
+                # immediately and its surplus tokens dropped — the device
+                # position overshot, but release resets the mirrors
+                # (paged: dirty re-upload; continuous: the next merge
+                # overwrites the slot's pos).
+                for slot in rnd[1]:
+                    n = int(n_np[slot])
+                    self.spec_proposed += self.speculate.k
+                    self.spec_accepted += n - 1
+                    for t in out_np[slot, :n]:
+                        done = self._advance_slot(slot, int(t))
+                        if done is not None:
+                            finished.append(done)
+                            break
+            elif rnd is not None:
+                for slot in rnd[1]:
+                    done = self._advance_slot(slot, int(toks[slot]))
                     if done is not None:
-                        break
-                if done is not None:
-                    finished.append(done)
-        elif self._round is not None:
-            tok_dev, active = self._round
-            self._round = None
-            toks = np.asarray(tok_dev)
-            for slot in active:
-                done = self._advance_slot(slot, int(toks[slot]))
-                if done is not None:
-                    finished.append(done)
+                        finished.append(done)
         return finished
 
     # -- migration seam (pause -> gather -> merge) --------------------------
@@ -1249,7 +1273,7 @@ class ServingEngine:
                                     sampling=sampling, speculate=speculate,
                                     draft_model=draft_model,
                                     draft_key=draft_key, mesh=mesh,
-                                    device=self.device)
+                                    device=self.device, clock=self.now)
             self.instances[inst_id] = inst
             self.scheduler.register(inst_id, alloc)
             ids.append(inst_id)
@@ -1386,6 +1410,25 @@ class ServingEngine:
         deadline = time.perf_counter() + budget_s
         worked_last_pass = False
         while time.perf_counter() < deadline:
+            granted = self._schedule()
+            if granted is None:
+                break
+            if not granted:
+                with TraceAnnotation("serve.quota_wait"):
+                    granted = self._await_grant(deadline,
+                                                spin=worked_last_pass)
+                if not granted:
+                    break  # idle, or out of budget
+            worked_last_pass = True
+            with TraceAnnotation("serve.pass"):
+                completed += self._run_pass(granted, overlap)
+        return completed
+
+    def _schedule(self) -> Optional[list[Token]]:
+        """Drop expired queued requests, request a token for every
+        instance with work, and run one scheduling round: the tokens it
+        granted, or None when no instance has work."""
+        with TraceAnnotation("serve.schedule"):
             self._expire_queued()
             any_work = False
             for inst_id, inst in list(self.instances.items()):
@@ -1393,51 +1436,67 @@ class ServingEngine:
                     any_work = True
                     self.scheduler.request_token(inst_id, self.now())
             if not any_work:
-                break
-            granted = self.scheduler.dispatch(self.now())
-            if not granted:
-                # Quota-blocked, not idle: when the previous pass did real
-                # work we are saturated and the next scheduling window is
-                # imminent — spin instead of yielding mid-burst.  Only a
-                # genuinely idle lull sleeps.
-                if not worked_last_pass and self.idle_sleep_s > 0:
-                    time.sleep(self.idle_sleep_s)
-                worked_last_pass = False
-                continue
-            worked_last_pass = True
-            t_prev = time.perf_counter()
-            if self.pump_delay_s > 0:
-                # Injected straggler stall: lands inside the timed region,
-                # so it inflates the pass latency the health EWMAs see and
-                # the Q_used the scheduler charges — a slow node looks
-                # slow everywhere, exactly like the real gray failure.
-                time.sleep(self.pump_delay_s)
-            if overlap:
-                # Only fused instances join the early dispatch pass: their
-                # dispatch_step is a cheap async enqueue.  Host-synchronous
-                # modes (static, fused=False) execute their whole round in
-                # dispatch_step, so they run in the sync pass where their
-                # compute is timed against their own Q_used, not the
-                # first-synced sibling's.
-                for token in granted:
-                    inst = self.instances[token.pod_id]
-                    if inst.fused:
-                        inst.dispatch_step()
-            # Sync pass: each instance's elapsed is the wall-clock delta to
-            # its sync point — the clock starts BEFORE the dispatch pass,
-            # so the full pass wall time (host dispatch overhead included,
-            # exactly what the serialized path charged) is apportioned
-            # across the overlapped instances without double-charging
-            # Q_used; the first-synced instance absorbs the (cheap,
-            # enqueue-only) dispatch leg.
+                return None
+            return self.scheduler.dispatch(self.now())
+
+    def _await_grant(self, deadline: float, spin: bool
+                     ) -> Optional[list[Token]]:
+        """Schedule again until a token is granted; None when no instance
+        has work left, [] when the budget runs out first.
+
+        Quota-blocked, not idle: when the previous pass did real work
+        (``spin``) we are saturated and the next scheduling window is
+        imminent — spin instead of yielding mid-burst.  Only a genuinely
+        idle lull sleeps."""
+        while True:
+            if not spin and self.idle_sleep_s > 0:
+                time.sleep(self.idle_sleep_s)
+            spin = False
+            if time.perf_counter() >= deadline:
+                return []
+            granted = self._schedule()
+            if granted is None or granted:
+                return granted
+
+    def _run_pass(self, granted: list[Token], overlap: bool) -> int:
+        """One granted pass: dispatch every granted instance's step, sync
+        each, land its tokens and charge its time; returns the requests
+        the pass completed."""
+        completed = 0
+        t_prev = time.perf_counter()
+        if self.pump_delay_s > 0:
+            # Injected straggler stall: lands inside the timed region, so
+            # it inflates the pass latency the health EWMAs see and the
+            # Q_used the scheduler charges — a slow node looks slow
+            # everywhere, exactly like the real gray failure.
+            time.sleep(self.pump_delay_s)
+        if overlap:
+            # Only fused instances join the early dispatch pass: their
+            # dispatch_step is a cheap async enqueue.  Host-synchronous
+            # modes (static, fused=False) execute their whole round in
+            # dispatch_step, so they run in the sync pass where their
+            # compute is timed against their own Q_used, not the
+            # first-synced sibling's.
             for token in granted:
                 inst = self.instances[token.pod_id]
-                if not overlap or not inst.fused:
+                if inst.fused:
                     inst.dispatch_step()
-                finished = inst.sync_step()
-                t_now = time.perf_counter()
-                elapsed = t_now - t_prev
-                t_prev = t_now
+        # Sync pass: each instance's elapsed is the wall-clock delta to its
+        # sync point — the clock starts BEFORE the dispatch pass, so the
+        # full pass wall time (host dispatch overhead included, exactly
+        # what the serialized path charged) is apportioned across the
+        # overlapped instances without double-charging Q_used; the
+        # first-synced instance absorbs the (cheap, enqueue-only)
+        # dispatch leg.
+        for token in granted:
+            inst = self.instances[token.pod_id]
+            if not overlap or not inst.fused:
+                inst.dispatch_step()
+            finished = inst.sync_step()
+            t_now = time.perf_counter()
+            elapsed = t_now - t_prev
+            t_prev = t_now
+            with TraceAnnotation("serve.land"):
                 self._observe_pass(elapsed)
                 # Drained occupancy scales with slot fill: an underfilled
                 # decode round cannot saturate the instance's SM share.
@@ -1520,22 +1579,19 @@ class ServingEngine:
 
     # -- hot-path telemetry -------------------------------------------------
 
-    def sync_counts(self) -> dict[str, int]:
-        """Per-instance host-synchronisation counts.  The fused hot path's
-        budget is exactly ONE per instance per pump pass (prefill argmaxes
-        and the decode round share it); the host-argmax reference spends
-        one per admitted prompt plus one per round."""
-        return {k: v.sync_count for k, v in self.instances.items()}
-
-    def telemetry(self) -> dict[str, dict[str, int]]:
-        """Hot-path counters per instance: steps, host syncs, (paged)
-        device-state uploads — ``uploads << steps`` proves the block
-        tables/positions stay device-resident between admission events —
-        plus prefix-sharing hits and COW resolutions and the count of
-        queued requests expired past their deadline."""
+    def telemetry(self) -> dict[str, dict[str, float]]:
+        """Hot-path counters per instance: steps, host syncs (the fused
+        path's budget is ONE per pass; the host-argmax reference spends one
+        per admitted prompt plus one per round), (paged) device-state
+        uploads — ``uploads << steps`` proves the block tables/positions
+        stay device-resident between admission events — plus
+        prefix-sharing hits and COW resolutions, the count of queued
+        requests expired past their deadline, and ``token_wait_s``: the
+        seconds the instance waited for the node scheduler's tokens."""
         return {k: {"steps": v.steps, "syncs": v.sync_count,
                     "uploads": v.uploads, "shared_hits": v.shared_block_hits,
                     "cow": v.cow_count, "spec_proposed": v.spec_proposed,
                     "spec_accepted": v.spec_accepted,
-                    "expired": self._expired.get(k, 0)}
+                    "expired": self._expired.get(k, 0),
+                    "token_wait_s": self.scheduler.pods[k].token_wait_s}
                 for k, v in self.instances.items()}

@@ -130,3 +130,20 @@ def test_quota_isolation_property(q_limit, n_windows):
         now = round(now, 10)
     for w, used in per_window.items():
         assert used <= q_limit + step + 1e-9
+
+
+def test_token_wait_accrues_while_quota_blocked():
+    """Virtual time: a pod that used its whole limit by t=0.2 asks again
+    at once and is held until the window rolls at t=1.0 — 0.8 s of
+    token wait.  A repeated request keeps its first time."""
+    ts = TokenScheduler(window=1.0)
+    ts.register("a", alloc(0.5, 0.2, 0.2))
+    ts.request_token("a", 0.0)
+    assert len(ts.dispatch(0.0)) == 1
+    ts.complete("a", elapsed=0.2, now=0.2)  # Q_used 0.2 = Q_limit
+    ts.request_token("a", 0.2)
+    assert ts.dispatch(0.2) == []
+    ts.request_token("a", 0.6)  # the engine re-requests every loop turn
+    assert ts.dispatch(0.6) == []
+    assert len(ts.dispatch(1.0)) == 1  # window rolled: quota reset
+    assert ts.pods["a"].token_wait_s == pytest.approx(0.8)
