@@ -67,9 +67,10 @@ def qwen2_7b_cut(n_layers: int = 16) -> ModelConfig:
 
     16 of 28 layers are 9.64 GB of bf16 weights.  A v5e lets a program
     use 15.75 GB, so that leaves room for the two instances' paged KV
-    pools, the second copy of a pool that each decode step's layer scan
-    makes (``transformer.decode_step_paged`` re-stacks the pool as the
-    scan's output), and the float32 reference's upcast embedding (2.2 GB).
+    pools and the float32 reference's upcast embedding (2.2 GB).  A
+    decode step updates its pool in place (the layer scan of
+    ``transformer.decode_step_paged`` carries it), so it holds no second
+    copy.
     """
     return dataclasses.replace(qwen2_7b.config(), n_layers=n_layers,
                                name=f"qwen2-7b-{n_layers}l")

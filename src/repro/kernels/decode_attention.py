@@ -27,18 +27,18 @@ from repro.kernels import interpret_mode
 NEG_INF = -1e30
 
 
-def _kernel(*refs, paged: bool, quant: bool, block_s: int, n_blocks: int,
+def _kernel(*refs, n_pre: int, quant: bool, block_s: int, n_blocks: int,
             n_kv: int, window: Optional[int], scale: float):
     """One kv-block step for every KV head of one sequence.
 
-    ``refs`` = scalar prefetch (``len_ref`` [, ``tbl_ref``]), then q, k, v
-    [, k_scale, v_scale], the output, and the acc / m / l scratch.  Paged
+    ``refs`` = ``n_pre`` scalar prefetch operands (``len_ref`` [,
+    ``tbl_ref`` [, ``layer_ref``]]), then q, k, v [, k_scale, v_scale],
+    the output, and the acc / m / l scratch.  Paged
     grids walk LOGICAL block slots: the physical page each step streams
     was picked by the K/V index map from ``tbl_ref``, so only a
     sequence's own blocks leave HBM; past-the-end slots point at the null
     block and are masked by ``cache_len`` exactly like dense padding.
     """
-    n_pre = 2 if paged else 1
     len_ref = refs[0]
     q_ref, k_ref, v_ref = refs[n_pre:n_pre + 3]
     ks_ref, vs_ref = refs[n_pre + 3:n_pre + 5] if quant else (None, None)
@@ -91,29 +91,38 @@ def _kernel(*refs, paged: bool, quant: bool, block_s: int, n_blocks: int,
 def _decode_call(q: jax.Array, kv: tuple, cache_len: jax.Array,
                  block_tables: Optional[jax.Array], *, block_s: int,
                  n_blocks: int, window: Optional[int],
-                 interpret: Optional[bool]) -> jax.Array:
+                 interpret: Optional[bool],
+                 layer: Optional[jax.Array] = None) -> jax.Array:
     """Shared ``pallas_call`` of the four decode variants.
 
     ``kv`` is (k, v) or (k, v, k_scale, v_scale) — dense (B, S, K, ...)
-    caches when ``block_tables`` is None, else (N, bs, K, ...) pages.
+    caches when ``block_tables`` is None, else (N, bs, K, ...) pages, or
+    stacked (L, N, bs, K, ...) pools read at ``layer``.
     """
     b, _, h, d = q.shape
-    n_kv = kv[0].shape[2]
+    n_kv = kv[0].shape[-2]
     g = h // n_kv
-    paged = block_tables is not None
-    kernel = functools.partial(
-        _kernel, paged=paged, quant=len(kv) == 4, block_s=block_s,
-        n_blocks=n_blocks, n_kv=n_kv, window=window, scale=d ** -0.5)
-    if paged:
+    lead = (None,)
+    if block_tables is None:
+        def kv_index(ib, ik, len_ref):
+            return ib, ik, 0, 0
+        prefetch = (cache_len.astype(jnp.int32),)
+    elif layer is None:
         def kv_index(ib, ik, len_ref, tbl_ref):
             return tbl_ref[ib, ik], 0, 0, 0
         prefetch = (cache_len.astype(jnp.int32),
                     block_tables.astype(jnp.int32))
     else:
-        def kv_index(ib, ik, len_ref):
-            return ib, ik, 0, 0
-        prefetch = (cache_len.astype(jnp.int32),)
-    kv_specs = [pl.BlockSpec((None, block_s, n_kv, x.shape[-1]), kv_index)
+        def kv_index(ib, ik, len_ref, tbl_ref, layer_ref):
+            return layer_ref[0], tbl_ref[ib, ik], 0, 0, 0
+        prefetch = (cache_len.astype(jnp.int32),
+                    block_tables.astype(jnp.int32),
+                    jnp.reshape(layer, (1,)).astype(jnp.int32))
+        lead = (None, None)
+    kernel = functools.partial(
+        _kernel, n_pre=len(prefetch), quant=len(kv) == 4, block_s=block_s,
+        n_blocks=n_blocks, n_kv=n_kv, window=window, scale=d ** -0.5)
+    kv_specs = [pl.BlockSpec((*lead, block_s, n_kv, x.shape[-1]), kv_index)
                 for x in kv]
     head_spec = pl.BlockSpec((None, n_kv, g, d), lambda ib, ik, *_:
                              (ib, 0, 0, 0))
@@ -153,20 +162,22 @@ def paged_decode_attention_pallas(
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, M) int32
     cache_len: jax.Array,     # (B,) int32
+    layer: Optional[jax.Array] = None,  # () int32: pages are (L, N, ...)
     *,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Paged single-token GQA decode: grid = (batch, table slot).
 
-    ``block_tables`` and ``cache_len`` ride in as scalar-prefetch operands
-    (``pltpu.PrefetchScalarGridSpec``) so the K/V index maps can pick the
-    PHYSICAL page for each logical slot before the DMA is issued — the
-    TPU-native equivalent of vLLM's paged attention.
+    ``block_tables`` and ``cache_len`` (and ``layer``, for stacked pools)
+    ride in as scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``)
+    so the K/V index maps can pick the PHYSICAL page for each logical slot
+    before the DMA is issued — the TPU-native equivalent of vLLM's paged
+    attention.
     """
     return _decode_call(q, (k_pages, v_pages), cache_len, block_tables,
-                        block_s=k_pages.shape[1],
+                        block_s=k_pages.shape[-3],
                         n_blocks=block_tables.shape[1], window=None,
-                        interpret=interpret)
+                        interpret=interpret, layer=layer)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -178,15 +189,16 @@ def paged_decode_attention_quant_pallas(
     v_scale: jax.Array,
     block_tables: jax.Array,  # (B, M) int32
     cache_len: jax.Array,     # (B,) int32
+    layer: Optional[jax.Array] = None,  # () int32: pages are (L, N, ...)
     *,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """int8-KV paged variant: codes + per-row scales stream per physical
     block and dequantize in VMEM (1 byte/element over the wire)."""
     return _decode_call(q, (k_pages, v_pages, k_scale, v_scale), cache_len,
-                        block_tables, block_s=k_pages.shape[1],
+                        block_tables, block_s=k_pages.shape[-3],
                         n_blocks=block_tables.shape[1], window=None,
-                        interpret=interpret)
+                        interpret=interpret, layer=layer)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))

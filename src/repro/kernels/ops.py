@@ -383,13 +383,16 @@ def verify_attention(
     return out.reshape(b, w, h, d).astype(q.dtype)
 
 
-def _gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
+def _gather_pages(pages: jax.Array, block_tables: jax.Array,
+                  layer: Optional[jax.Array] = None) -> jax.Array:
     """(N, bs, K, D) physical pages + (B, M) block table -> contiguous
-    (B, M*bs, K, D) caches in logical order (reference materialization)."""
+    (B, M*bs, K, D) caches in logical order (reference materialization).
+    Given ``layer``, ``pages`` is the stacked (L, N, bs, K, D) pool and the
+    one gather ``pages[layer, block_tables]`` reads it in place: no layer
+    is sliced out first."""
     b, m = block_tables.shape
-    _, bs = pages.shape[:2]
-    g = pages[block_tables]  # (B, M, bs, ...)
-    return g.reshape(b, m * bs, *pages.shape[2:])
+    g = pages[block_tables] if layer is None else pages[layer, block_tables]
+    return g.reshape(b, m * g.shape[2], *g.shape[3:])  # from (B, M, bs, ...)
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
@@ -400,6 +403,7 @@ def paged_decode_attention(
     block_tables: jax.Array,  # (B, M) int32 — physical block per logical slot
     cache_len: jax.Array,     # (B,) int32 — valid prefix length
     *,
+    layer: Optional[jax.Array] = None,  # () int32: pages are (L, N, ...)
     backend: str = DEFAULT_BACKEND,
 ) -> jax.Array:
     """Single-token GQA attention against a block-paged KV cache.
@@ -408,13 +412,15 @@ def paged_decode_attention(
     maps, streaming only each sequence's own blocks from HBM; the xla/ref
     fallback materializes the gather and reuses ``decode_attention``.
     Padded table entries (the null block) are masked by ``cache_len``.
+    With ``layer``, both read that layer of stacked pools in place.
     """
     if backend == "pallas":
         from repro.kernels import decode_attention as _da
         return _da.paged_decode_attention_pallas(q, k_pages, v_pages,
-                                                 block_tables, cache_len)
-    k = _gather_pages(k_pages, block_tables)
-    v = _gather_pages(v_pages, block_tables)
+                                                 block_tables, cache_len,
+                                                 layer)
+    k = _gather_pages(k_pages, block_tables, layer)
+    v = _gather_pages(v_pages, block_tables, layer)
     return decode_attention(q, k, v, cache_len, backend=backend)
 
 
@@ -426,12 +432,13 @@ def paged_verify_attention(
     block_tables: jax.Array,  # (B, M) int32
     cache_len: jax.Array,     # (B,) int32 — valid rows before the window
     *,
+    layer: Optional[jax.Array] = None,  # () int32: pages are (L, N, ...)
     backend: str = DEFAULT_BACKEND,
 ) -> jax.Array:
     """``verify_attention`` against a block-paged KV cache (gather
     materialization, same per-query-row causal mask)."""
-    k = _gather_pages(k_pages, block_tables)
-    v = _gather_pages(v_pages, block_tables)
+    k = _gather_pages(k_pages, block_tables, layer)
+    v = _gather_pages(v_pages, block_tables, layer)
     return verify_attention(q, k, v, cache_len, backend=backend)
 
 
@@ -445,18 +452,20 @@ def paged_decode_attention_quant(
     block_tables: jax.Array,  # (B, M) int32
     cache_len: jax.Array,     # (B,) int32
     *,
+    layer: Optional[jax.Array] = None,  # () int32: pages are (L, N, ...)
     backend: str = DEFAULT_BACKEND,
 ) -> jax.Array:
     """Paged decode attention over int8 KV blocks (§Perf D x paging)."""
     if backend == "pallas":
         from repro.kernels import decode_attention as _da
         return _da.paged_decode_attention_quant_pallas(
-            q, k_pages, v_pages, k_scale, v_scale, block_tables, cache_len)
+            q, k_pages, v_pages, k_scale, v_scale, block_tables, cache_len,
+            layer)
     return decode_attention_quant(
-        q, _gather_pages(k_pages, block_tables),
-        _gather_pages(v_pages, block_tables),
-        _gather_pages(k_scale, block_tables),
-        _gather_pages(v_scale, block_tables),
+        q, _gather_pages(k_pages, block_tables, layer),
+        _gather_pages(v_pages, block_tables, layer),
+        _gather_pages(k_scale, block_tables, layer),
+        _gather_pages(v_scale, block_tables, layer),
         cache_len, backend=backend)
 
 

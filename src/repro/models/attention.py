@@ -267,8 +267,12 @@ def attn_decode(params: dict, x: jax.Array, kc: jax.Array, vc: jax.Array,
 
 def paged_cache_write(pages: jax.Array, new: jax.Array,
                       block_tables: jax.Array, pos: jax.Array,
-                      active: Optional[jax.Array] = None) -> jax.Array:
-    """Write one token's (B, 1, K, Dh) K/V into (N, bs, K, Dh) pages.
+                      active: Optional[jax.Array] = None,
+                      layer: Optional[jax.Array] = None) -> jax.Array:
+    """Write one token's (B, 1, K, Dh) K/V into (N, bs, K, Dh) pages, or,
+    given ``layer``, into that layer of a stacked (L, N, bs, K, Dh) pool
+    (one scatter at ``[layer, blk, pos % bs]``, so a pool carried through
+    the layer scan is updated in place).
 
     Each sequence's row lands in physical block ``tables[b, pos[b]//bs]``
     at offset ``pos[b] % bs``.  Live sequences own disjoint WRITABLE
@@ -289,38 +293,48 @@ def paged_cache_write(pages: jax.Array, new: jax.Array,
     NORMALIZED (to the last physical block — a live sequence's page)
     before out-of-bounds handling ever sees it.
     """
-    bs = pages.shape[1]
+    lead = () if layer is None else (layer,)
+    n_blocks, bs = pages.shape[len(lead):len(lead) + 2]
     blk = jnp.take_along_axis(block_tables, (pos // bs)[:, None], axis=1)[:, 0]
     if active is not None:
-        blk = jnp.where(active.astype(bool), blk, pages.shape[0])
-    return pages.at[blk, pos % bs].set(new[:, 0].astype(pages.dtype),
-                                       mode="drop")
+        blk = jnp.where(active.astype(bool), blk, n_blocks)
+    row = new[:, 0].astype(pages.dtype)  # (B, K, Dh)
+    if pages.shape[-1] == 1:
+        # int8 scale pools: index each head too.  A (K, 1) update window
+        # makes the TPU lay the whole stacked pool out K-minor, padded to
+        # 128 lanes, and copy it in and out of the layer scan.
+        heads = jnp.arange(row.shape[1])
+        return pages.at[(*lead, blk[:, None], (pos % bs)[:, None], heads)
+                        ].set(row, mode="drop")
+    return pages.at[(*lead, blk, pos % bs)].set(row, mode="drop")
 
 
 def attn_decode_paged(params: dict, x: jax.Array,
                       k_pages: jax.Array, v_pages: jax.Array,
                       block_tables: jax.Array, pos: jax.Array,
                       cfg: ModelConfig,
-                      active: Optional[jax.Array] = None
+                      active: Optional[jax.Array] = None,
+                      layer: Optional[jax.Array] = None
                       ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One-token self-attention against (and updating) a paged cache.
 
     x: (B, 1, D); k_pages/v_pages: (N, bs, K, Dh) physical blocks shared
-    by the whole batch; block_tables: (B, M) int32; pos: (B,) absolute
-    position of each sequence's new token; ``active`` optionally masks
-    free slots' writes out (see paged_cache_write).  Returns
-    (output, k', v').
+    by the whole batch, or the stacked (L, N, bs, K, Dh) pools with the
+    ``layer`` index to write and read; block_tables: (B, M) int32; pos:
+    (B,) absolute position of each sequence's new token; ``active``
+    optionally masks free slots' writes out (see paged_cache_write).
+    Returns (output, k', v').
     """
     positions = pos[:, None]
     q = _project_q(params, x, cfg)
     k, v = _project_kv(params, x, cfg)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    k_pages = paged_cache_write(k_pages, k, block_tables, pos, active)
-    v_pages = paged_cache_write(v_pages, v, block_tables, pos, active)
+    k_pages = paged_cache_write(k_pages, k, block_tables, pos, active, layer)
+    v_pages = paged_cache_write(v_pages, v, block_tables, pos, active, layer)
     cache_len = (pos + 1).astype(jnp.int32)
     o = ops.paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                   cache_len)
+                                   cache_len, layer=layer)
     return _output(params, o), k_pages, v_pages
 
 
@@ -364,14 +378,16 @@ def attn_verify_paged(params: dict, x: jax.Array,
                       k_pages: jax.Array, v_pages: jax.Array,
                       block_tables: jax.Array, pos: jax.Array,
                       cfg: ModelConfig,
-                      active: Optional[jax.Array] = None
+                      active: Optional[jax.Array] = None,
+                      layer: Optional[jax.Array] = None
                       ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """W-token verify attention against (and updating) a paged cache.
 
     Scatters the window's rows one position at a time (W is small — the
     draft length plus one) through ``paged_cache_write`` so inactive
     slots' rows drop and the COW write contract stays per-position, then
-    attends with the per-query-row causal mask.
+    attends with the per-query-row causal mask.  Pages and ``layer`` as
+    in ``attn_decode_paged``.
     """
     w = x.shape[1]
     positions = pos[:, None] + jnp.arange(w)[None, :]
@@ -381,11 +397,11 @@ def attn_verify_paged(params: dict, x: jax.Array,
     k = apply_rope(k, positions, cfg.rope_theta)
     for j in range(w):
         k_pages = paged_cache_write(k_pages, k[:, j:j + 1], block_tables,
-                                    pos + j, active)
+                                    pos + j, active, layer)
         v_pages = paged_cache_write(v_pages, v[:, j:j + 1], block_tables,
-                                    pos + j, active)
+                                    pos + j, active, layer)
     o = ops.paged_verify_attention(q, k_pages, v_pages, block_tables,
-                                   pos.astype(jnp.int32))
+                                   pos.astype(jnp.int32), layer=layer)
     return _output(params, o), k_pages, v_pages
 
 
@@ -394,7 +410,8 @@ def attn_decode_paged_quant(params: dict, x: jax.Array,
                             ks_pages: jax.Array, vs_pages: jax.Array,
                             block_tables: jax.Array, pos: jax.Array,
                             cfg: ModelConfig,
-                            active: Optional[jax.Array] = None
+                            active: Optional[jax.Array] = None,
+                            layer: Optional[jax.Array] = None
                             ) -> tuple[jax.Array, jax.Array, jax.Array,
                                        jax.Array, jax.Array]:
     """attn_decode_paged against int8 code + scale pages (§Perf D)."""
@@ -405,13 +422,18 @@ def attn_decode_paged_quant(params: dict, x: jax.Array,
     k = apply_rope(k, positions, cfg.rope_theta)
     k8, ks_new = kv_quantize(k)
     v8, vs_new = kv_quantize(v)
-    k_pages = paged_cache_write(k_pages, k8, block_tables, pos, active)
-    v_pages = paged_cache_write(v_pages, v8, block_tables, pos, active)
-    ks_pages = paged_cache_write(ks_pages, ks_new, block_tables, pos, active)
-    vs_pages = paged_cache_write(vs_pages, vs_new, block_tables, pos, active)
+    k_pages = paged_cache_write(k_pages, k8, block_tables, pos, active,
+                                layer)
+    v_pages = paged_cache_write(v_pages, v8, block_tables, pos, active,
+                                layer)
+    ks_pages = paged_cache_write(ks_pages, ks_new, block_tables, pos, active,
+                                 layer)
+    vs_pages = paged_cache_write(vs_pages, vs_new, block_tables, pos, active,
+                                 layer)
     cache_len = (pos + 1).astype(jnp.int32)
     o = ops.paged_decode_attention_quant(q, k_pages, v_pages, ks_pages,
-                                         vs_pages, block_tables, cache_len)
+                                         vs_pages, block_tables, cache_len,
+                                         layer=layer)
     return _output(params, o), k_pages, v_pages, ks_pages, vs_pages
 
 

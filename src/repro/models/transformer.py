@@ -125,12 +125,14 @@ def block_decode(lp: dict, x: jax.Array, kc: jax.Array, vc: jax.Array,
 def block_decode_paged(lp: dict, x: jax.Array, kc: jax.Array, vc: jax.Array,
                        block_tables: jax.Array, pos: jax.Array,
                        cfg: ModelConfig,
-                       active: Optional[jax.Array] = None
+                       active: Optional[jax.Array] = None,
+                       layer: Optional[jax.Array] = None
                        ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """block_decode against one layer's paged KV blocks."""
+    """block_decode against one layer's paged KV blocks (or layer
+    ``layer`` of the stacked pools)."""
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     a, kc, vc = attn.attn_decode_paged(lp["attn"], h, kc, vc,
-                                       block_tables, pos, cfg, active)
+                                       block_tables, pos, cfg, active, layer)
     x = named(x + a, "batch", "seq", None)
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     m, _ = _ffn(lp, h, cfg, train=False)
@@ -140,10 +142,12 @@ def block_decode_paged(lp: dict, x: jax.Array, kc: jax.Array, vc: jax.Array,
 def block_decode_paged_quant(lp: dict, x: jax.Array, kc, vc, ksc, vsc,
                              block_tables: jax.Array, pos: jax.Array,
                              cfg: ModelConfig,
-                             active: Optional[jax.Array] = None):
+                             active: Optional[jax.Array] = None,
+                             layer: Optional[jax.Array] = None):
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     a, kc, vc, ksc, vsc = attn.attn_decode_paged_quant(
-        lp["attn"], h, kc, vc, ksc, vsc, block_tables, pos, cfg, active)
+        lp["attn"], h, kc, vc, ksc, vsc, block_tables, pos, cfg, active,
+        layer)
     x = named(x + a, "batch", "seq", None)
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     m, _ = _ffn(lp, h, cfg, train=False)
@@ -578,6 +582,30 @@ def supports_paged(cfg: ModelConfig) -> bool:
             and cfg.local_global_ratio == 0)
 
 
+def _paged_layer_scan(params: dict, x: jax.Array, cache: dict,
+                      keys: tuple[str, ...], layer_fn
+                      ) -> tuple[jax.Array, dict]:
+    """Run ``layer_fn(lp, x, pools, layer) -> (x, pools)`` over the layers
+    with the stacked (L, N, bs, ...) pools ``cache[keys]`` in the carry.
+
+    Each layer scatters its rows into the carried pools and gathers its
+    pages from them by layer index, so a donated cache is updated in
+    place.  Scanning the pools as ``xs``/``ys`` instead slices every
+    layer's pool out and re-stacks it, which XLA compiles to whole-pool
+    copies on every step.  Returns (x, cache with the updated pools).
+    """
+    def body(carry, xs):
+        x, pools = carry
+        lp, layer = xs
+        return layer_fn(lp, x, pools, layer), None
+
+    n_layers = cache[keys[0]].shape[0]
+    (x, pools), _ = jax.lax.scan(
+        body, (x, {k: cache[k] for k in keys}),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, dict(cache, **pools)
+
+
 def decode_step_paged(params: dict, token: jax.Array, cache: dict,
                       block_tables: jax.Array, pos: jax.Array,
                       cfg: ModelConfig,
@@ -591,7 +619,8 @@ def decode_step_paged(params: dict, token: jax.Array, cache: dict,
     slots to physical blocks; pos: (B,) int32 absolute positions;
     ``active`` ((B,), optional) suppresses free slots' KV writes.  The
     caller owns block allocation and position bookkeeping — this step
-    only writes one row per sequence and attends its table.  Returns
+    only writes one row per sequence and attends its table, in place in
+    the pools the layer scan carries (``_paged_layer_scan``).  Returns
     (logits (B, V), updated cache).
     """
     if not supports_paged(cfg):
@@ -603,27 +632,23 @@ def decode_step_paged(params: dict, token: jax.Array, cache: dict,
     block_tables = jnp.asarray(block_tables, jnp.int32)
 
     if attn.kv_int8_enabled(cfg):
-        def qbody(x, xs):
-            lp, kc, vc, ksc, vsc = xs
-            x, kc, vc, ksc, vsc = block_decode_paged_quant(
-                lp, x, kc, vc, ksc, vsc, block_tables, pos, cfg, active)
-            return x, (kc, vc, ksc, vsc)
+        keys = ("k", "v", "k_scale", "v_scale")
 
-        x, (kn, vn, ksn, vsn) = jax.lax.scan(
-            qbody, x, (params["layers"], cache["k"], cache["v"],
-                       cache["k_scale"], cache["v_scale"]))
-        new_cache = dict(cache, k=kn, v=vn, k_scale=ksn, v_scale=vsn)
-        return lm_head(params, x, cfg)[:, 0], new_cache
+        def layer_fn(lp, x, p, layer):
+            x, *pools = block_decode_paged_quant(
+                lp, x, *(p[k] for k in keys), block_tables, pos, cfg,
+                active, layer)
+            return x, dict(zip(keys, pools))
+    else:
+        keys = ("k", "v")
 
-    def body(x, xs):
-        lp, kc, vc = xs
-        x, kc, vc = block_decode_paged(lp, x, kc, vc, block_tables, pos,
-                                       cfg, active)
-        return x, (kc, vc)
+        def layer_fn(lp, x, p, layer):
+            x, kc, vc = block_decode_paged(lp, x, p["k"], p["v"],
+                                           block_tables, pos, cfg, active,
+                                           layer)
+            return x, {"k": kc, "v": vc}
 
-    x, (kn, vn) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    new_cache = dict(cache, k=kn, v=vn)
+    x, new_cache = _paged_layer_scan(params, x, cache, keys, layer_fn)
     return lm_head(params, x, cfg)[:, 0], new_cache
 
 
@@ -711,11 +736,12 @@ def block_verify(lp: dict, x: jax.Array, kc: jax.Array, vc: jax.Array,
 def block_verify_paged(lp: dict, x: jax.Array, kc: jax.Array, vc: jax.Array,
                        block_tables: jax.Array, pos: jax.Array,
                        cfg: ModelConfig,
-                       active: Optional[jax.Array] = None
+                       active: Optional[jax.Array] = None,
+                       layer: Optional[jax.Array] = None
                        ) -> tuple[jax.Array, jax.Array, jax.Array]:
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     a, kc, vc = attn.attn_verify_paged(lp["attn"], h, kc, vc,
-                                       block_tables, pos, cfg, active)
+                                       block_tables, pos, cfg, active, layer)
     x = x + a
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     m, _ = _ffn(lp, h, cfg, train=False)
@@ -779,12 +805,10 @@ def verify_step_paged(params: dict, tokens: jax.Array, cache: dict,
     pos = jnp.asarray(pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
 
-    def body(x, xs):
-        lp, kc, vc = xs
-        x, kc, vc = block_verify_paged(lp, x, kc, vc, block_tables, pos,
-                                       cfg, active)
-        return x, (kc, vc)
+    def layer_fn(lp, x, p, layer):
+        x, kc, vc = block_verify_paged(lp, x, p["k"], p["v"], block_tables,
+                                       pos, cfg, active, layer)
+        return x, {"k": kc, "v": vc}
 
-    x, (kn, vn) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    return lm_head(params, x, cfg), dict(cache, k=kn, v=vn)
+    x, cache = _paged_layer_scan(params, x, cache, ("k", "v"), layer_fn)
+    return lm_head(params, x, cfg), cache

@@ -210,6 +210,31 @@ def test_paged_decode_quant_pallas_vs_dequant_ref(shape):
                                **_tol(jnp.bfloat16))
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_paged_decode_reads_one_layer_of_stacked_pools(backend, quant):
+    """``layer=`` on stacked (L, N, ...) pools reads exactly what the same
+    call reads from that layer's (N, ...) pages."""
+    from repro.models.attention import kv_quantize
+
+    b, h, k, d, bs, m, n = PAGED_SHAPES[0]
+    rng = np.random.default_rng(7)
+    q = _rand(rng, (b, 1, h, d), jnp.bfloat16)
+    pools = [_rand(rng, (3, n, bs, k, d), jnp.bfloat16) for _ in range(2)]
+    if quant:
+        pools = [x for p in pools for x in kv_quantize(p)]
+        pools = [pools[0], pools[2], pools[1], pools[3]]  # k, v, ks, vs
+        fn = ops.paged_decode_attention_quant
+    else:
+        fn = ops.paged_decode_attention
+    tables, cache_len = _paged_tables(rng, b, m, n, bs)
+    layer = jnp.int32(1)
+    got = fn(q, *pools, tables, cache_len, layer=layer, backend=backend)
+    want = fn(q, *(p[1] for p in pools), tables, cache_len, backend=backend)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
 WKV_SHAPES = [
     # (B, S, H, D, bt)
     (2, 16, 2, 8, 8),

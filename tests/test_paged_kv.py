@@ -168,6 +168,82 @@ def test_append_gather_pages_roundtrip(tiny_model, tiny_params):
             np.asarray(entry[key], np.float32), err_msg=key)
 
 
+def _xs_ys_round(params, tok, cache, tables, pos, active, cfg):
+    """The fused paged round as its layer scan stood before the pools rode
+    in the carry: each layer's pages sliced out as ``xs``, written and
+    read, and re-stacked as ``ys``."""
+    from repro.models import attention, transformer
+
+    keys = (("k", "v", "k_scale", "v_scale")
+            if attention.kv_int8_enabled(cfg) else ("k", "v"))
+    block = (transformer.block_decode_paged_quant if len(keys) == 4
+             else transformer.block_decode_paged)
+
+    def body(x, xs):
+        lp, *pages = xs
+        x, *pages = block(lp, x, *pages, tables, pos, cfg, active)
+        return x, tuple(pages)
+
+    x = transformer.embed_tokens(params, tok[:, None], cfg)
+    x, pools = jax.lax.scan(body, x, (params["layers"],
+                                      *(cache[k] for k in keys)))
+    logits = transformer.lm_head(params, x, cfg)[:, 0]
+    return (transformer.greedy_tokens(logits, cfg),
+            dict(cache, **dict(zip(keys, pools))), pos + active)
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_carried_pool_round_matches_xs_ys_scan(tiny_model, tiny_params,
+                                               monkeypatch, kv_int8):
+    """Fused paged rounds with the pools carried through the layer scan
+    are bit-identical to the old xs/ys scan: tokens, positions and every
+    pool, with two free slots and a prefix block shared by two sequences;
+    free slots write nothing, so the null block stays zero."""
+    if kv_int8:
+        monkeypatch.setenv("REPRO_KV_INT8", "1")
+    else:
+        monkeypatch.delenv("REPRO_KV_INT8", raising=False)
+    bs, n_blocks = 4, 12
+    shapes = tiny_model.paged_cache_shapes(n_blocks, bs)
+    keys = jax.random.split(jax.random.key(3), len(shapes))
+    cache = {}
+    for key, (name, sds) in zip(keys, sorted(shapes.items())):
+        if sds.dtype == jnp.int8:
+            pool = jax.random.randint(key, sds.shape, -127, 128, jnp.int32)
+        else:
+            pool = jax.random.uniform(key, sds.shape, jnp.float32, 0.01, 1.0)
+        cache[name] = pool.astype(sds.dtype).at[:, 0].set(0)  # null block
+    # Slots 0 and 1 share prefix block 1 (rows 0-3) and write past it;
+    # slots 2 and 4 are free; slot 3 owns its blocks.
+    tables = jnp.asarray([[1, 2, 6], [1, 3, 7], [0, 0, 0], [4, 5, 8],
+                          [0, 0, 0]], jnp.int32)
+    pos = jnp.asarray([6, 5, 0, 7, 3], jnp.int32)
+    active = jnp.asarray([1, 1, 0, 1, 0], jnp.int32)
+    tok = jnp.asarray([5, 9, 0, 17, 0], jnp.int32)
+    new = jax.jit(tiny_model.decode_step_paged_tokens)
+    old = jax.jit(lambda *a: _xs_ys_round(*a, tiny_model.cfg))
+    state_new = state_old = (tok, cache, pos)
+    for _ in range(5):
+        state_new = new(tiny_params, state_new[0], state_new[1], tables,
+                        state_new[2], active)
+        state_old = old(tiny_params, state_old[0], state_old[1], tables,
+                        state_old[2], active)
+        np.testing.assert_array_equal(np.asarray(state_new[0]),
+                                      np.asarray(state_old[0]))
+        np.testing.assert_array_equal(np.asarray(state_new[2]),
+                                      np.asarray(state_old[2]))
+    assert np.asarray(state_new[2]).tolist() == [11, 10, 0, 12, 3]
+    assert set(state_new[1]) == set(shapes)
+    for name in shapes:
+        got = np.asarray(state_new[1][name])
+        np.testing.assert_array_equal(got, np.asarray(state_old[1][name]),
+                                      err_msg=name)
+        assert not got[:, 0].any(), f"{name}: the null block was written"
+        np.testing.assert_array_equal(got[:, 1], np.asarray(cache[name][:, 1]),
+                                      err_msg=f"{name}: shared block written")
+        assert (got != np.asarray(cache[name])).any(), f"{name}: unwritten"
+
+
 # -- engine: block budgeting, release, reuse -------------------------------
 
 

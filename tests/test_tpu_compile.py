@@ -9,6 +9,9 @@ worker collects the same tests and only the worker given this file loads
 the TPU library.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -61,19 +64,66 @@ def _i32(sharding, *shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
 
-def test_fused_paged_decode_step_compiles(one_chip, model, smoke):
-    """The engine's fused paged round (``decode_paged_tok``): weights, a
-    2048-block pool and its per-step copy fit the chip."""
+def _compile_paged_round(one_chip, model, smoke):
     m = smoke.SmokeConfig(model=model.cfg).max_len // BLOCK
     step = jax.jit(lambda *a: model.decode_step_paged_tokens(*a),
                    donate_argnums=(1, 2, 4))
-    compiled = step.lower(
+    return step.lower(
         _on(one_chip, model.abstract_params()), _i32(one_chip, BATCH),
         _on(one_chip, model.paged_cache_shapes(N_BLOCKS, BLOCK)),
         _i32(one_chip, BATCH, m), _i32(one_chip, BATCH),
         _i32(one_chip, BATCH)).compile()
-    mem = compiled.memory_analysis()
+
+
+# `%name = bf16[16,2048,16,4,128]{...} opcode(` — array results only.
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _moves_of_shape(hlo: str, shapes: set) -> list:
+    """Instructions of ``hlo`` (fused ones included) that copy, slice or
+    update-slice a result of one of ``shapes``, unit dims ignored."""
+    hits = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, dims, opcode = m.groups()
+        moves = opcode in _MOVES or (
+            opcode == "fusion" and any(w in name for w in _MOVES))
+        dims = tuple(int(d) for d in dims.split(",") if d and d != "1")
+        if moves and dims in shapes:
+            hits.append(line.strip())
+    return hits
+
+
+def test_fused_paged_decode_step_compiles(one_chip, model, smoke):
+    """The engine's fused paged round (``decode_paged_tok``): weights and
+    a 2048-block pool fit the chip."""
+    mem = _compile_paged_round(one_chip, model, smoke).memory_analysis()
     assert mem.argument_size_in_bytes > 9e9  # 16 layers of bf16 weights
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8"])
+def test_fused_paged_round_updates_the_pool_in_place(one_chip, model, smoke,
+                                                     monkeypatch, kv_int8):
+    """The layer scan carries the stacked pools: no step copies a K/V pool
+    or slices a layer's pool out and re-stacks it, so the step's
+    temporaries stay below one layer's K pages.  (int8's per-row scale
+    pools, 1/128 of the codes, may be staged through on-chip memory.)"""
+    if kv_int8:
+        monkeypatch.setenv("REPRO_KV_INT8", "1")
+    else:
+        monkeypatch.delenv("REPRO_KV_INT8", raising=False)
+    pools = model.paged_cache_shapes(N_BLOCKS, BLOCK)
+    compiled = _compile_paged_round(one_chip, model, smoke)
+    k = pools["k"]
+    layer_k_bytes = math.prod(k.shape[1:]) * k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
+    shapes = {tuple(d for d in shape if d != 1)
+              for p in (pools["k"], pools["v"])
+              for shape in (p.shape, p.shape[1:])}
+    assert _moves_of_shape(compiled.as_text(), shapes) == []
 
 
 def test_bucketed_prefill_compiles(one_chip, model, smoke):
@@ -88,9 +138,11 @@ def test_bucketed_prefill_compiles(one_chip, model, smoke):
     assert compiled.memory_analysis().output_size_in_bytes > 0
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+@pytest.mark.parametrize("paged", [True, False, "stacked"],
+                         ids=["paged", "dense", "stacked"])
 def test_pallas_decode_kernel_compiles(one_chip, model, smoke, paged):
-    """Both decode kernels lower to Mosaic (``tpu_custom_call``) for v5e."""
+    """Both decode kernels lower to Mosaic (``tpu_custom_call``) for v5e,
+    the paged one also reading a layer of stacked (L, N, ...) pools."""
     cfg = model.cfg
     max_len = smoke.SmokeConfig(model=cfg).max_len
     bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
@@ -98,11 +150,14 @@ def test_pallas_decode_kernel_compiles(one_chip, model, smoke, paged):
     q = bf16(BATCH, 1, cfg.n_heads, cfg.dh)
     cache_len = _i32(one_chip, BATCH)
     if paged:
-        pages = bf16(N_BLOCKS, BLOCK, cfg.n_kv_heads, cfg.dh)
+        lead = (cfg.n_layers,) if paged == "stacked" else ()
+        layer = (_i32(one_chip),) if lead else ()
+        pages = bf16(*lead, N_BLOCKS, BLOCK, cfg.n_kv_heads, cfg.dh)
         fn = jax.jit(lambda *a: decode_attention
                      .paged_decode_attention_pallas(*a, interpret=False))
         lowered = fn.lower(q, pages, pages,
-                           _i32(one_chip, BATCH, max_len // BLOCK), cache_len)
+                           _i32(one_chip, BATCH, max_len // BLOCK), cache_len,
+                           *layer)
     else:
         cache = bf16(BATCH, 2 * PROMPT, cfg.n_kv_heads, cfg.dh)
         fn = jax.jit(lambda *a: decode_attention.decode_attention_pallas(
