@@ -9,13 +9,15 @@ keeps a norm as an offset from 1 (it computes ``1 + w``), so it is given
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from benchmarks.chip.references.qwen2 import weight_specs
 from benchmarks.chip.weights import base_key, global_tensors, stacked_layers
+from repro.distributed.sharding import serve_pspec, tree_shardings
 from repro.models import build_model
 from repro.models.config import ModelConfig
 
@@ -88,10 +90,14 @@ def build(cfg: dict) -> Any:
     return build_model(program_config(cfg))
 
 
-def make_params(cfg: dict, model: Any, lo: Any, hi: Any) -> Any:
+def make_params(cfg: dict, model: Any, lo: Any, hi: Any,
+                mesh: Optional[Mesh] = None) -> Any:
     """Every weight, made on the device in one jitted call from the seed's
     words, in the dtype the program serves it in; checked against the
-    program's own parameter shapes."""
+    program's own parameter shapes.  With a ``mesh`` (a tensor-parallel
+    pod) each weight is drawn straight into the placement the program
+    serves it under (``serve_pspec``), so no device holds the whole tree;
+    the values are the same as on one device."""
     gspecs, lspecs = weight_specs(cfg)
     n = cfg["num_hidden_layers"]
     vpad = model.cfg.padded_vocab
@@ -109,4 +115,7 @@ def make_params(cfg: dict, model: Any, lo: Any, hi: Any) -> Any:
                 jax.tree_util.tree_leaves(want)))):
         raise ValueError("the program's parameter layout has changed: "
                          f"{jax.tree_util.tree_map(lambda a: a.shape, want)}")
-    return jax.jit(make)(lo, hi)
+    if mesh is None:
+        return jax.jit(make)(lo, hi)
+    return jax.jit(make, out_shardings=tree_shardings(
+        model.param_names(), want, mesh, resolver=serve_pspec))(lo, hi)

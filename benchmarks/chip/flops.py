@@ -73,28 +73,47 @@ def served_flops(cfg: dict, requests: list[tuple[int, int]],
                  prefix_hit_tokens: int) -> int:
     """Useful FLOPs of ``(prompt length, tokens delivered)`` requests whose
     prompts were computed in the window: each prompt, then every delivered
-    token after the first (which the prompt's pass yields).  Prompt tokens
-    served from the prefix cache are taken out at the longest context of
-    any prompt, so the count is a lower bound."""
+    token after the first (which the prompt's pass yields)."""
+    return part_flops(cfg, [(p, 0, n) for p, n in requests],
+                      prefix_hit_tokens)
+
+
+def part_flops(cfg: dict, requests: list[tuple[int, int, int]],
+               prefix_hit_tokens: int) -> int:
+    """Useful FLOPs of the delivered tokens ``first`` to ``last - 1`` of
+    ``(prompt length, first, last)`` requests, counted from 0: token 0 is
+    the prompt's pass (the whole prompt and the head once), token j > 0 a
+    decode step at context prompt + j.  Prompt tokens served from the
+    prefix cache are taken out at the longest context of any prompt
+    counted, so the count is a lower bound."""
     total = 0
     longest = 0
-    for n_prompt, n_out in requests:
-        if n_out == 0:
-            continue
-        longest = max(longest, n_prompt)
-        total += prompt_flops(cfg, n_prompt)
-        total += sum(decode_flops(cfg, n_prompt + j) for j in range(1, n_out))
+    for n_prompt, first, last in requests:
+        if first == 0 and last > 0:
+            longest = max(longest, n_prompt)
+            total += prompt_flops(cfg, n_prompt)
+        total += sum(decode_flops(cfg, n_prompt + j)
+                     for j in range(max(first, 1), last))
     return max(total - prefix_hit_tokens * token_flops(cfg, longest), 0)
 
 
 def busy_mfu(run: Any) -> Optional[float]:
-    """Useful FLOPs of a run's window over its device busy seconds (from
-    the trace) times the chip's peak bf16 FLOP/s, in percent."""
+    """Useful FLOPs of the traced part of a run's window (the tokens
+    delivered in it, and the prefix hits counted in it) over the device
+    busy seconds of every chip it ran on (the trace's mean busy seconds
+    times its ``devices``) times one chip's peak bf16 FLOP/s, in
+    percent."""
     if run.trace is None or run.trace["busy_s"] <= 0 or run.peak is None:
         return None
-    reqs = [(s.prompt_len, len(run.times(s))) for s in run.sent]
-    useful = served_flops(run.cfg, reqs,
-                          run.telemetry["shared_hits"] * run.block_size)
+    lo = run.traced_from
+    reqs = []
+    for s in run.sent:
+        ts = run.times(s)
+        first = 0 if lo is None else sum(t < lo for t in ts)
+        reqs.append((s.prompt_len, first, len(ts)))
+    tel = run.telemetry if lo is None else run.traced_telemetry
+    useful = part_flops(run.cfg, reqs, tel["shared_hits"] * run.block_size)
     if not useful:
         return None
-    return 100.0 * useful / (run.trace["busy_s"] * run.peak.bf16_flops)
+    chip_s = run.trace["busy_s"] * run.trace["devices"]
+    return 100.0 * useful / (chip_s * run.peak.bf16_flops)

@@ -3,15 +3,18 @@
     python3 benchmarks/chip/run.py --workload <name> --seed <n> \
         --seconds <s> --trace <0|1>
 
-One process: make the weights on the device from the seed, deploy them
-through ``ClusterFrontend.deploy``, warm the prompt lengths the cell's
-traffic can send, drive ``ClusterFrontend.submit`` / ``pump`` for
-``--seconds``, check the served tokens against the plain reference, and
-print one JSON object as the last line of standard output.  With
-``--trace 0`` its metrics are the cell's end-to-end metrics, measured from
-the client's side; with ``--trace 1`` the window is traced and the metrics
-are the cell's per-layer metrics.  Without a TPU, or with fewer chips
-than the cell asks for, it exits non-zero and prints no result.
+One process: make the weights on the device from the seed (in their
+shards, where the configuration's instance is a tensor-parallel pod over
+several chips), deploy them through ``ClusterFrontend.deploy``, warm the
+prompt lengths the cell's traffic can send, drive
+``ClusterFrontend.submit`` / ``pump`` for ``--seconds``, check the served
+tokens against the plain reference, and print one JSON object as the last
+line of standard output. With ``--trace 0`` its metrics are the cell's
+end-to-end metrics, measured from the client's side; with ``--trace 1``
+the window (or its last ``trace.last_seconds``, where the configuration
+names them) is traced and the metrics are the cell's per-layer metrics.
+Without a TPU, or with fewer chips than the cell asks for, it exits
+non-zero and prints no result.
 """
 
 from __future__ import annotations
@@ -127,6 +130,10 @@ class RunRecord:
     block_size: int
     trace: Optional[dict] = None
     peak: Optional[peaks.Peak] = None
+    # Where the trace covers only the window's last part: the host time it
+    # began, and the counters' change from then to the close.
+    traced_from: Optional[float] = None
+    traced_telemetry: Optional[dict] = None
 
     def times(self, s: Sent) -> list[float]:
         return [t for t in s.times if t <= self.end]
@@ -144,18 +151,67 @@ def telemetry(frontend: Any) -> dict:
 # -- the served path ------------------------------------------------------------
 
 
+def cell_devices(cell: dict, platform: str) -> list:
+    """The devices a cell runs on: the first ``chips`` of the host's, which
+    must be of ``platform`` and at least that many."""
+    devices = jax.devices()
+    if devices[0].platform != platform or len(devices) < cell["chips"]:
+        raise BenchError(f"need {cell['chips']} {platform} device(s), found "
+                         f"{len(devices)} {devices[0].platform}")
+    return devices[:cell["chips"]]
+
+
+def shards(cfg: dict) -> int:
+    """How many chips one instance spans (a tensor-parallel pod)."""
+    return cfg["deployment"].get("shards", 1)
+
+
+def served_model(cfg: dict, seed: int, devices: list) -> tuple[Any, Any]:
+    """The program's model for ``cfg`` and its weights from ``seed``: on the
+    default device, or drawn straight into the shards of a pod over the
+    first ``shards(cfg)`` of ``devices``."""
+    from repro.distributed.sharding import tp_mesh
+    n = shards(cfg)
+    if n > len(devices):
+        raise BenchError(f"{cfg['name']} spans {n} chips; the cell has "
+                         f"{len(devices)}")
+    ad = adapter(cfg)
+    model = ad.build(cfg)
+    params = ad.make_params(cfg, model, *seed_words(seed),
+                            mesh=tp_mesh(n, devices[:n]))
+    return model, params
+
+
+def pod_admission_bytes(params: Any, n: int) -> int:
+    """The memory a pod member may be charged for.  The program charges
+    every member of a tensor-parallel pod the whole parameter tree
+    (``ClusterFrontend.place_instance``), though a member holds only its
+    shard of it; the bytes the devices report are raised by the
+    difference, so admission still weighs what the fullest member holds,
+    its share of the KV pool included, against its memory."""
+    from repro.core.model_sharing import pytree_nbytes
+    from repro.serving.engine import per_device_bytes
+    from repro.serving.frontend import device_mem_bytes, node_devices
+    held = max(per_device_bytes(params).values())
+    return device_mem_bytes(node_devices(n)) + pytree_nbytes(params) - held
+
+
 def deploy(cfg: dict, mix: dict, model: Any, params: Any) -> Any:
     from repro.core.resources import Alloc
     from repro.serving.frontend import ClusterFrontend
     dep = cfg["deployment"]
     bs = dep["block_size"]
     max_len = -(-traffic.max_rows(mix) // bs) * bs
-    frontend = ClusterFrontend(n_nodes=1)
+    n = shards(cfg)
+    frontend = ClusterFrontend(
+        n_nodes=n, mem_bytes=pod_admission_bytes(params, n) if n > 1
+        else None)
     frontend.deploy(FN, model, params, Alloc(**dep["alloc"]),
                     n_instances=dep["instances"],
                     max_batch=dep["slots_per_instance"], max_len=max_len,
                     batching="paged", block_size=bs,
-                    prefix_sharing=dep["prefix_sharing"])
+                    n_kv_blocks=dep.get("kv_blocks"),
+                    prefix_sharing=dep["prefix_sharing"], shards=n)
     return frontend
 
 
@@ -198,11 +254,24 @@ def annotate(on: bool) -> Callable[[str], Any]:
     return jax.profiler.TraceAnnotation
 
 
+def traced_seconds(cfg: dict, seconds: float) -> Optional[float]:
+    """How much of the window's end a traced run profiles, where the
+    configuration's ``trace.last_seconds`` asks for less than the whole
+    window; None for the whole window."""
+    last = cfg.get("trace", {}).get("last_seconds")
+    return last if last is not None and last < seconds else None
+
+
 def serve_window(frontend: Any, plan: traffic.Traffic, seconds: float,
-                 span: Callable[[str], Any]) -> tuple[list[Sent], float,
-                                                      list[float]]:
+                 span: Callable[[str], Any],
+                 traced_s: Optional[float] = None,
+                 start_trace: Callable[[], None] = lambda: None
+                 ) -> tuple[list[Sent], float, list[float]]:
     """Drive the frontend for ``seconds``; returns what was sent, the end
-    of the window and the open loop's submission lags."""
+    of the window and the open loop's submission lags.  The window's span
+    covers all of it, or with ``traced_s`` its last ``traced_s`` seconds:
+    ``start_trace`` is called as that part begins and the span opens when
+    it returns."""
     sent: list[Sent] = []
     live: list[Sent] = []
     lags: list[float] = []
@@ -225,11 +294,17 @@ def serve_window(frontend: Any, plan: traffic.Traffic, seconds: float,
 
     t0 = time.perf_counter()
     end = t0 + seconds
-    with span("bench.window"):
+    with contextlib.ExitStack() as window:
+        if traced_s is None:
+            window.enter_context(span(devtrace.WINDOW))
         while True:
             now = time.perf_counter()
             if now >= end:
                 break
+            if traced_s is not None and now >= end - traced_s:
+                traced_s = None
+                start_trace()
+                window.enter_context(span(devtrace.WINDOW))
             with span("bench.submit"):
                 if plan.loop == "open":
                     while (nxt < len(plan.arrivals)
@@ -313,32 +388,46 @@ def run_cell(cell: dict, cfg: dict, mix: dict, metrics: list[dict], *,
     per-layer ones.  ``platform`` is the platform the devices must be.
     ``compared``, when given, receives the requests held to the reference
     (``served``) and the gap of each served token (``gaps``)."""
-    devices = jax.devices()
-    if devices[0].platform != platform or len(devices) < cell["chips"]:
-        raise BenchError(f"need {cell['chips']} {platform} device(s), found "
-                         f"{len(devices)} {devices[0].platform}")
+    devices = cell_devices(cell, platform)
     device = devices[0]
-    ad = adapter(cfg)
-    model = ad.build(cfg)
-    params = ad.make_params(cfg, model, *seed_words(seed))
+    model, params = served_model(cfg, seed, devices)
     frontend = deploy(cfg, mix, model, params)
     warm_up(frontend, cfg, mix)
     plan = traffic.generate(mix, cfg["vocab_size"], seed, seconds)
     compiles = _count_compiles()
     tel0 = telemetry(frontend)
     trace_dir = OUT_DIR / "trace" / f"{cell['name']}-{seed}"
+    part = traced_seconds(cfg, seconds) if trace else None
+    traced: dict[str, Any] = {}
+
+    def start_trace() -> None:
+        t0 = time.perf_counter()
+        jax.profiler.start_trace(str(trace_dir))
+        traced.update(start=time.perf_counter(), tel=telemetry(frontend))
+        log(f"trace started in {traced['start'] - t0:.2f} s")
+
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(str(trace_dir))
+        if part is None:
+            jax.profiler.start_trace(str(trace_dir))
     setup_s = time.perf_counter() - PROCESS_START
-    sent, end, lags = serve_window(frontend, plan, seconds, annotate(trace))
+    sent, end, lags = serve_window(frontend, plan, seconds, annotate(trace),
+                                   part, start_trace)
     if trace:
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        log(f"trace written in {time.perf_counter() - t0:.1f} s")
     n_compiles = compiles()
-    tel = {k: v - tel0.get(k, 0) for k, v in telemetry(frontend).items()}
-    mem = (device.memory_stats() or {}).get("peak_bytes_in_use")
+    tel1 = telemetry(frontend)
+    tel = {k: v - tel0.get(k, 0) for k, v in tel1.items()}
+    mems = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
     rec = RunRecord(cfg, seconds, sent, end, lags, tel,
                     cfg["deployment"]["block_size"])
+    if traced:
+        rec.traced_from = traced["start"]
+        rec.traced_telemetry = {k: v - traced["tel"].get(k, 0)
+                                for k, v in tel1.items()}
     finished = [check.Served(np.asarray(s.req.prompt, np.int32),
                              np.asarray(s.req.tokens_out, np.int32))
                 for s in sent if s.req is not None and s.req.done
@@ -348,7 +437,9 @@ def run_cell(cell: dict, cfg: dict, mix: dict, metrics: list[dict], *,
     gc.collect()
     out: dict[str, Any] = {}
     if trace:
+        t0 = time.perf_counter()
         rec.trace = devtrace.summarize(devtrace.find_xplane(str(trace_dir)))
+        log(f"trace read in {time.perf_counter() - t0:.1f} s")
         rec.peak = peaks.peak(device.device_kind)
         if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -372,7 +463,9 @@ def run_cell(cell: dict, cfg: dict, mix: dict, metrics: list[dict], *,
     out["metrics"] = {k: {"value": v, "unit": units[k]}
                       for k, v in values.items() if k in units}
     out["device"] = {"platform": device.platform, "kind": device.device_kind,
-                     "count": len(devices), "memory_peak_bytes": mem}
+                     "count": len(jax.devices()),
+                     "memory_peak_bytes": max(mems, key=lambda m: m or 0),
+                     "memory_peak_bytes_by_device": mems}
     if trace:
         out["device"]["busy_s"] = rec.trace["busy_s"]
         out["device"]["window_s"] = rec.trace["window_s"]

@@ -1,11 +1,10 @@
 """Where the device's idle time went, by the serving path's own spans,
 and the device time of each compiled program.
 
-``trace.py`` names an idle gap after the harness span (``bench.*``) that
-overlaps it most.  The program also writes ``serve.*`` spans from inside
+The program writes ``serve.*`` spans from inside
 ``ServingEngine.pump`` (``serve.schedule``, ``serve.quota_wait``,
 ``serve.pass`` holding ``serve.admit``, ``serve.cow``, ``serve.dispatch``,
-``serve.sync`` and ``serve.land``) into the same trace, on the clock the
+``serve.sync`` and ``serve.land``) into the profiler's trace, on the clock the
 profiler aligns with the device's.  This module reduces a trace with
 them:
 
@@ -18,9 +17,7 @@ them:
 - ``idle_by_span``: device-idle seconds in the window by the innermost
   host span over them (a ``serve.*`` span, else a ``bench.*`` one, else
   ``no host span``), longest first;
-- ``idle_gaps``: the gaps ``trace.summarize`` lists, with the same
-  seconds, each name followed by the ``serve.*`` span that holds most of
-  the gap (``bench.pump>serve.land``);
+- ``idle_gaps``: the gaps ``trace.summarize`` lists, as it names them;
 - ``pump_idle_s``: device-idle seconds inside ``bench.pump``, and the
   part of them that a ``serve.*`` span holds;
 - ``sync_lag_s``: for each ``serve.sync``, its end less the end of the
@@ -31,8 +28,8 @@ them:
   gap lies that much earlier on the host clock than it did: the first
   millisecond or so after a ``serve.sync`` is put down to it.
 
-Without ``serve.*`` spans (a program that writes none) the gaps read as
-in ``trace.py`` and ``idle_by_span`` holds the harness spans alone.
+Without ``serve.*`` spans (a program that writes none) ``idle_by_span``
+holds the harness spans alone.
 
     python3 benchmarks/chip/tools/attribute_idle.py <trace dir>
 """
@@ -40,26 +37,14 @@ in ``trace.py`` and ``idle_by_span`` holds the harness spans alone.
 from __future__ import annotations
 
 import bisect
-from typing import Optional
 
 from jax.profiler import ProfileData
 
 from benchmarks.chip import trace as devtrace
 
-SERVE = "serve."
 PASS = "serve.pass"
 SYNC = "serve.sync"
 PUMP = "bench.pump"
-NO_SPAN = "no host span"
-
-
-def host_spans(pd: ProfileData) -> list[tuple[float, float, str]]:
-    """(start_ns, end_ns, name) of every ``bench.*`` and ``serve.*`` span
-    on the host planes."""
-    return [(ev.start_ns, ev.end_ns, ev.name)
-            for p in pd.planes if not p.name.startswith("/device:")
-            for line in p.lines for ev in line.events
-            if ev.name.startswith((devtrace.HOST_PREFIX, SERVE))]
 
 
 def program(module: str) -> str:
@@ -111,58 +96,6 @@ def sync_lag_s(spans: list[tuple[float, float, str]], plane, lo: float,
     return out
 
 
-def _innermost(open_spans: dict[int, tuple[float, str]], prefix: str
-               ) -> Optional[str]:
-    best = None
-    for start, name in open_spans.values():
-        if name.startswith(prefix) and name != devtrace.WINDOW and (
-                best is None or start >= best[0]):
-            best = (start, name)
-    return None if best is None else best[1]
-
-
-def attribute(intervals: list[tuple[float, float]],
-              spans: list[tuple[float, float, str]]
-              ) -> list[dict[tuple[Optional[str], Optional[str]], float]]:
-    """For each of the disjoint ``intervals`` (sorted), its ns by the
-    innermost (``bench.*``, ``serve.*``) span pair open over them; a side
-    with no span open is None.  Spans on one thread nest, so the
-    innermost open span is the one that started last."""
-    events = sorted([(s, 1, k) for k, (s, _, _) in enumerate(spans)]
-                    + [(e, 0, k) for k, (_, e, _) in enumerate(spans)])
-    open_spans: dict[int, tuple[float, str]] = {}
-
-    def apply(ev: tuple[float, int, int]) -> None:
-        _, starts, k = ev
-        if starts:
-            open_spans[k] = (spans[k][0], spans[k][2])
-        else:
-            open_spans.pop(k, None)
-
-    out = []
-    j = 0
-    for lo, hi in intervals:
-        while j < len(events) and events[j][0] <= lo:
-            apply(events[j])
-            j += 1
-        acc: dict[tuple[Optional[str], Optional[str]], float] = {}
-        t = lo
-        while True:
-            nxt = events[j][0] if j < len(events) and events[j][0] < hi \
-                else hi
-            if nxt > t:
-                key = (_innermost(open_spans, devtrace.HOST_PREFIX),
-                       _innermost(open_spans, SERVE))
-                acc[key] = acc.get(key, 0.0) + (nxt - t)
-                t = nxt
-            if nxt >= hi:
-                break
-            apply(events[j])
-            j += 1
-        out.append(acc)
-    return out
-
-
 def summarize(path: str, top: int = 10) -> dict:
     """``modules`` ({program: [executions, device seconds]}, device
     seconds a mean over the device planes), ``pass_host_s``,
@@ -170,7 +103,7 @@ def summarize(path: str, top: int = 10) -> dict:
     first), ``pump_idle_s`` ({"all", "serve"} seconds) and ``sync_lag_s``
     (on the first device plane)."""
     pd = ProfileData.from_file(path)
-    spans = host_spans(pd)
+    spans = devtrace.host_spans(pd)
     windows = [(s, e) for s, e, n in spans if n == devtrace.WINDOW]
     if not windows:
         raise ValueError(f"no {devtrace.WINDOW} span in {path}")
@@ -191,36 +124,22 @@ def summarize(path: str, top: int = 10) -> dict:
     edges = [lo] + [x for iv in union for x in iv] + [hi]
     gaps = [(edges[k], edges[k + 1]) for k in range(0, len(edges), 2)
             if edges[k + 1] > edges[k]]
-    by_gap = attribute(gaps, spans)
     by_span: dict[str, float] = {}
     pump = {"all": 0.0, "serve": 0.0}
-    for acc in by_gap:
+    for acc in devtrace.attribute(gaps, spans):
         for (bench, serve), t in acc.items():
-            name = serve or bench or NO_SPAN
+            name = devtrace.span_name((bench, serve))
             by_span[name] = by_span.get(name, 0.0) + t
             if bench == PUMP:
                 pump["all"] += t
                 pump["serve"] += t if serve else 0.0
-    others = [s for s in spans if s[2] != devtrace.WINDOW]
-    named = []
-    for k in sorted(range(len(gaps)),
-                    key=lambda k: gaps[k][0] - gaps[k][1])[:top]:
-        s, e = gaps[k]
-        name = devtrace._blame(s, e, others)
-        serve: dict[str, float] = {}
-        for (_, sv), t in by_gap[k].items():
-            if sv is not None:
-                serve[sv] = serve.get(sv, 0.0) + t
-        if serve:
-            name += ">" + max(serve, key=serve.get)
-        named.append([name, (e - s) * 1e-9])
     return {
         "modules": {n: [c, t * 1e-9] for n, (c, t) in sorted(
             progs.items(), key=lambda kv: -kv[1][1])},
         "pass_host_s": pass_host_s(spans, lo, hi),
         "idle_by_span": [[n, t * 1e-9] for n, t in sorted(
             by_span.items(), key=lambda kv: -kv[1])[:top]],
-        "idle_gaps": named,
+        "idle_gaps": devtrace.idle_gaps(gaps, spans, top),
         "pump_idle_s": {k: v * 1e-9 for k, v in pump.items()},
         "sync_lag_s": sync_lag_s(spans, planes[0], lo, hi),
     }

@@ -48,9 +48,22 @@ TINY_MOE = dict(
                     alloc={"sm": 1.0, "quota_request": 1.0,
                            "quota_limit": 1.0}))
 
+# One instance as a tensor-parallel pod over the four host devices, with a
+# KV head per device as Qwen2-7B's 4 over 4 chips.  (Where the KV heads do
+# not divide over the devices, the program's pool comes out of a step
+# sharded otherwise than it went in, and the window compiles again.)  Its
+# pool is 60 blocks, where 8 slots at the longest request would take 80.
+TINY_POD = dict(
+    TINY, name="tiny-qwen2-tp4", num_key_value_heads=4,
+    deployment=dict(TINY["deployment"], instances=1, shards=4,
+                    slots_per_instance=8, kv_blocks=60,
+                    alloc={"sm": 1.0, "quota_request": 1.0,
+                           "quota_limit": 1.0}))
+
 BENCH = run.load_benchmark()
 CHAT_CELL = {"name": "qwen2-7b-16l.chat-prefix", "chips": 1}
 AGENT_CELL = {"name": "qwen2-moe-a2.7b-8l.agent-decode", "chips": 1}
+POD_CELL = {"name": "qwen2-7b-tp4.chat-prefix", "chips": 4}
 
 
 def tiny_chat() -> dict:
@@ -195,6 +208,131 @@ def test_trace_reduction_on_a_recorded_chip_trace():
     assert s["idle_gaps"][0][1] == pytest.approx(0.01, rel=0.5)
 
 
+def test_collective_time_is_the_union_of_collective_ops():
+    ops = [
+        (0, 10, "%fusion.1 = bf16[8]{0} fusion(%p)"),
+        (5, 20, "%all-gather-start.3 = (bf16[8]{0}) all-gather-start(%a)"),
+        (15, 30, "%all-gather-done.3 = bf16[32]{0} all-gather-done(%s)"),
+        (40, 50, "%all-reduce.7 = f32[8]{0} all-reduce(%b)"),
+        (45, 48, "%reduce-scatter.1 = f32[2]{0} reduce-scatter(%c)"),
+        (60, 70, "%collective-permute-done = f32[8]{0} "
+                 "collective-permute-done(%d)"),
+        (80, 90, "%all-to-all.2 = f32[8]{0} all-to-all(%e)"),
+        (95, 99, "%copy-start = f32[8]{0} copy-start(%f)")]
+    # [5, 30) from the overlapping halves, [40, 50), [60, 70), [80, 90).
+    assert devtrace.collective_ns(ops, 0, 100) == 25 + 10 + 10 + 10
+    assert devtrace.collective_ns(ops, 10, 85) == 20 + 10 + 10 + 5
+    assert devtrace.collective_ns(ops[:1] + ops[-1:], 0, 100) == 0
+
+
+def test_mfu_divides_by_every_chip_of_the_trace():
+    s = run.Sent(req=None, due=0.0, sent=0.0, prompt_len=40)
+    s.times = [0.5, 0.6, 0.7]
+    rec = run.RunRecord(cfg=TINY, seconds=10.0, sent=[s], end=10.0,
+                        lags=[], telemetry={"shared_hits": 0},
+                        block_size=16, peak=peaks.peak("TPU v5 lite"),
+                        trace={"busy_s": 2.0, "window_s": 10.0,
+                               "devices": 1})
+    useful = flops.served_flops(TINY, [(40, 3)], 0)
+    one = flops.busy_mfu(rec)
+    assert one == pytest.approx(100.0 * useful / (2.0 * 197e12))
+    rec.trace["devices"] = 4
+    assert flops.busy_mfu(rec) == pytest.approx(one / 4)
+    rec.trace["collective_s"] = 0.5
+    read = run.load_module("layer_metrics", "collective_share").read
+    assert read(rec) == pytest.approx(25.0)
+    assert run.load_module("layer_metrics", "mfu.tp4").read(rec) == \
+        pytest.approx(one / 4)
+
+
+def test_mfu_of_a_traced_part_counts_the_work_done_in_it():
+    """Where the trace covers only the window's end, the useful FLOPs are
+    those of the tokens delivered after it began, a prompt only where its
+    first token came then, and the prefix hits counted then."""
+    early, late = (run.Sent(req=None, due=0.0, sent=0.0, prompt_len=40),
+                   run.Sent(req=None, due=0.0, sent=0.0, prompt_len=24))
+    early.times, late.times = [0.5, 0.6, 0.7], [0.65, 0.8]
+    rec = run.RunRecord(cfg=TINY, seconds=1.0, sent=[early, late], end=1.0,
+                        lags=[], telemetry={"shared_hits": 3},
+                        block_size=16, peak=peaks.peak("TPU v5 lite"),
+                        trace={"busy_s": 0.25, "window_s": 0.45,
+                               "devices": 4},
+                        traced_from=0.55,
+                        traced_telemetry={"shared_hits": 1})
+    useful = (flops.decode_flops(TINY, 41) + flops.decode_flops(TINY, 42)
+              + flops.prompt_flops(TINY, 24) + flops.decode_flops(TINY, 25)
+              - 16 * flops.token_flops(TINY, 24))
+    assert flops.part_flops(TINY, [(40, 1, 3), (24, 0, 2)], 16) == useful
+    assert flops.busy_mfu(rec) == pytest.approx(
+        100.0 * useful / (0.25 * 4 * 197e12))
+    # A trace that began before every token reads as the whole window.
+    rec.traced_from, rec.traced_telemetry = 0.0, rec.telemetry
+    whole = flops.busy_mfu(rec)
+    rec.traced_from = None
+    assert flops.busy_mfu(rec) == whole
+
+
+def test_the_trace_covers_the_part_a_configuration_asks_for():
+    cfg = {"trace": {"last_seconds": 10}}
+    assert run.traced_seconds(cfg, 50.0) == 10
+    assert run.traced_seconds(cfg, 8.0) is None
+    assert run.traced_seconds(TINY, 50.0) is None
+    assert run.traced_seconds(run.load_json("configs", "qwen2-7b-tp4"),
+                              50.0) == 10
+    for name in ("qwen2-7b-16l", "qwen2-moe-a2.7b-8l"):
+        assert run.traced_seconds(run.load_json("configs", name),
+                                  50.0) is None
+
+
+@pytest.mark.parametrize("traced_s", [None, 0.15], ids=["whole", "last"])
+def test_the_window_span_opens_where_the_trace_begins(traced_s):
+    """The window span covers the whole window, or opens as the traced part
+    begins, right after the trace is started, and closes at the end."""
+    import contextlib
+    import time
+
+    class Idle:
+        def has_work(self):
+            return False
+
+    events = []
+
+    @contextlib.contextmanager
+    def span(name):
+        events.append(("open", name, time.perf_counter()))
+        yield
+        events.append(("close", name, time.perf_counter()))
+
+    def start_trace():
+        events.append(("start", None, time.perf_counter()))
+
+    plan = traffic.Traffic(loop="open", arrivals=[], clients=[])
+    t0 = time.perf_counter()
+    _, end, _ = run.serve_window(Idle(), plan, 0.4, span, traced_s,
+                                 start_trace)
+    assert end - t0 == pytest.approx(0.4, abs=0.05)
+    window = [e for e in events if e[1] == devtrace.WINDOW]
+    assert [e[0] for e in window] == ["open", "close"]
+    assert window[1][2] >= end
+    starts = [e for e in events if e[0] == "start"]
+    if traced_s is None:
+        assert events[0] == window[0] and not starts
+    else:
+        assert len(starts) == 1
+        assert end - traced_s <= starts[0][2] <= window[0][2]
+        assert window[0][2] < end - traced_s + 0.05
+
+
+def test_the_pod_mix_is_chat_prefix_at_its_own_rate():
+    chat = run.load_json("traffic", "chat-prefix")
+    pod = run.load_json("traffic", "chat-prefix-tp4")
+    for mix in (chat, pod):
+        del mix["rate_per_s"], mix["why"]["rate"]
+    assert pod == chat
+    cfg = run.load_json("configs", "qwen2-7b-tp4")
+    assert run.shards(cfg) == 4 and cfg["num_hidden_layers"] == 28
+
+
 def test_merge_is_the_union_of_intervals():
     assert devtrace.merge([(5, 7), (0, 2), (1, 3), (7, 8)]) == [(0, 3),
                                                                (5, 8)]
@@ -233,8 +371,9 @@ def test_a_metric_without_workloads_reaches_every_cell():
 
 
 @pytest.mark.parametrize("cfg,mix,cell", [
-    (TINY, tiny_chat(), CHAT_CELL), (TINY_MOE, tiny_agent(), AGENT_CELL)],
-    ids=["dense-chat", "moe-agent"])
+    (TINY, tiny_chat(), CHAT_CELL), (TINY_MOE, tiny_agent(), AGENT_CELL),
+    (TINY_POD, tiny_chat(), POD_CELL)],
+    ids=["dense-chat", "moe-agent", "dense-pod-chat"])
 def test_a_sound_run_is_correct(cfg, mix, cell):
     out = run_tiny(cfg, mix, cell)
     assert out["correct"], out["checks"]
@@ -242,7 +381,13 @@ def test_a_sound_run_is_correct(cfg, mix, cell):
     assert set(out["metrics"]) == {
         m["name"] for m in run.cell_metrics(BENCH, cell, trace=False)}
     assert out["compiles_in_window"] == 0
+    assert len(out["device"]["memory_peak_bytes_by_device"]) == cell["chips"]
     assert list(out)[-1] == "checks"
+
+
+def test_a_pod_needs_as_many_chips_as_it_has_shards():
+    with pytest.raises(run.BenchError, match="spans 4 chips"):
+        run_tiny(TINY_POD, tiny_chat(), dict(POD_CELL, chips=2))
 
 
 def _token_off_by_one(monkeypatch):
@@ -260,14 +405,42 @@ def _kv_never_written(monkeypatch):
                         lambda pages, *a, **k: pages)
 
 
-@pytest.mark.parametrize("fault", [_token_off_by_one, _kv_never_written],
-                         ids=["token-altered", "state-unchanged"])
-def test_a_broken_served_path_is_not_correct(fault, monkeypatch):
+def _exchange_left_out(monkeypatch):
+    """Each chip of a pod goes on with its own heads' share of the
+    attention output, never summed with the other chips' shares."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import SERVE_AXIS, current_mesh
+    from repro.models import attention
+
+    def local_output(params, o):
+        b, s, h, dh = o.shape
+
+        def part(o_here, wo):
+            n = o_here.shape[2] * dh
+            rows = jax.lax.dynamic_slice_in_dim(
+                wo, jax.lax.axis_index(SERVE_AXIS) * n, n)
+            return o_here.reshape(b, s, n) @ rows
+
+        return jax.shard_map(
+            part, mesh=current_mesh(),
+            in_specs=(P(None, None, SERVE_AXIS, None), P()), out_specs=P(),
+            check_vma=False)(o, params["wo"])
+
+    monkeypatch.setattr(attention, "_output", local_output)
+
+
+@pytest.mark.parametrize("fault,cfg,cell", [
+    (_token_off_by_one, TINY, CHAT_CELL),
+    (_kv_never_written, TINY, CHAT_CELL),
+    (_exchange_left_out, TINY_POD, POD_CELL)],
+    ids=["token-altered", "state-unchanged", "exchange-left-out"])
+def test_a_broken_served_path_is_not_correct(fault, cfg, cell, monkeypatch):
     fault(monkeypatch)
-    out = run_tiny(TINY, tiny_chat(), CHAT_CELL)
+    out = run_tiny(cfg, tiny_chat(), cell)
     assert not out["correct"]
     assert out["checks"]["max_logit_gap"]["value"] > \
-        TINY["correct"]["max_logit_gap"]
+        cfg["correct"]["max_logit_gap"]
 
 
 def test_the_lower_precision_control_is_not_correct():
@@ -305,3 +478,32 @@ def test_reference_is_the_same_with_layers_drawn_one_at_a_time():
     assert jnp.allclose(1.0 + params["layers"]["ln1"][1].astype(jnp.float32),
                         gain, atol=1e-2)
     assert jax.devices()[0].platform == "cpu"
+
+
+def test_pod_weights_are_drawn_in_their_shards():
+    """Drawn straight into the pod's placement, every weight has the value
+    of the one-device draw, bit for bit, and the sharding the program
+    serves it under; no device holds the whole tree."""
+    from jax.sharding import NamedSharding
+
+    from benchmarks.chip.adapters import qwen2 as adapter
+    from benchmarks.chip.weights import seed_words
+    from repro.distributed.sharding import serve_pspec, tp_mesh
+    from repro.serving.engine import per_device_bytes
+    model = adapter.build(TINY)
+    lo, hi = seed_words(2**40 + 3)
+    one = adapter.make_params(TINY, model, lo, hi)
+    mesh = tp_mesh(4, jax.devices()[:4])
+    pod = adapter.make_params(TINY, model, lo, hi, mesh=mesh)
+    names = jax.tree_util.tree_leaves(
+        model.param_names(), is_leaf=lambda x: isinstance(x, tuple))
+    leaves = jax.tree_util.tree_leaves(pod)
+    assert len(names) == len(leaves)
+    for a, b, nm in zip(jax.tree_util.tree_leaves(one), leaves, names):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        want = NamedSharding(mesh, serve_pspec(nm, b.shape, mesh))
+        assert b.sharding.is_equivalent_to(want, b.ndim), nm
+    held = per_device_bytes(pod)
+    assert sorted(held) == [d.id for d in jax.devices()[:4]]
+    total = sum(x.nbytes for x in leaves)
+    assert max(held.values()) < 0.6 * total

@@ -25,7 +25,6 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[3]))
 
 from benchmarks.chip import run, traffic  # noqa: E402
-from benchmarks.chip.weights import seed_words  # noqa: E402
 
 
 def unfinished(sent: list[run.Sent], t: float) -> int:
@@ -57,9 +56,8 @@ def main() -> int:
     cfg = run.load_json("configs", cell["config"])
     mix = run.load_json("traffic", cell["traffic"])
     run.configure_cache()
-    ad = run.adapter(cfg)
-    model = ad.build(cfg)
-    params = ad.make_params(cfg, model, *seed_words(args.seed))
+    model, params = run.served_model(cfg, args.seed,
+                                     run.cell_devices(cell, "tpu"))
     frontend = run.deploy(cfg, mix, model, params)
     run.warm_up(frontend, cfg, mix)
     for rate in args.rates:
